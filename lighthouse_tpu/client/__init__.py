@@ -29,6 +29,60 @@ from ..logs import get_logger
 log = get_logger("client")
 
 
+def assemble_device_path(bls_backend: str) -> None:
+    """Node assembly of the verification path: the BLS backend and, for the
+    ``jax`` backend, the persistent compile cache, the device mesh, the
+    async device pipeline and the autotune controller.  ``build`` calls it;
+    ``chip_smoke.py`` calls it before driving a harness, so both run the
+    same assembly.  A compile-cache or mesh failure raises: a node that
+    cannot cache or shard must not start as if it could."""
+    from ..crypto.bls.backends import set_backend
+
+    set_backend(bls_backend)  # node assembly selects the device path
+    if bls_backend == "jax":
+        # Persistent compile cache + optional AOT bucket warmup
+        # (ops/compile_cache.py): cold XLA compiles are paid once per
+        # binary, not per node restart — and with
+        # LIGHTHOUSE_TPU_AOT_WARMUP=1 the standard buckets compile on a
+        # background thread before the first batch arrives.
+        from ..ops import compile_cache
+
+        compile_cache.configure_persistent_cache()
+        compile_cache.maybe_warmup_from_env()
+        # Mesh sharding (device_mesh.py): LIGHTHOUSE_TPU_MESH=N|auto
+        # shards every bucketed device op's batch axis over the device
+        # mesh.  Configured eagerly at node assembly so the topology (and
+        # its per-device breakers) is logged and gauged before traffic
+        # arrives; <2 devices means single-device dispatch.
+        from .. import device_mesh
+
+        device_mesh.configure()
+        # Async device pipeline (device_pipeline.py): production nodes
+        # stream every signature-set group through the persistent device
+        # worker so block import / gossip / sync-committee work coalesce
+        # into maximal device batches.  LIGHTHOUSE_TPU_DEVICE_PIPELINE=0
+        # opts out (device_pipeline.enable honors it).
+        from .. import device_pipeline
+
+        device_pipeline.enable()
+        # Self-tuning control plane (autotune.py): under
+        # LIGHTHOUSE_TPU_AUTOTUNE=live this measures the fq backend
+        # (FQ_BACKEND=auto only; cached per device kind) and starts the
+        # periodic controller that overlays bucket vocabularies from the
+        # flight recorder.  The default mode (pinned) starts nothing —
+        # decisions then replay only from an installed pin.
+        try:
+            from .. import autotune
+
+            autotune.maybe_start_from_env()
+        except Exception:
+            log.warning("autotune startup failed", exc_info=True)
+    if os.environ.get("LIGHTHOUSE_TPU_DEVICE_SHA") == "1":
+        from ..ops.sha256_device import install_device_hash
+
+        install_device_hash()  # bulk Merkle layers on the device VPU
+
+
 class ClientBuilder:
     """Staged assembly; each ``with_*`` returns self (builder.rs style)."""
 
@@ -170,59 +224,7 @@ class ClientBuilder:
             self._genesis_state, anchor_block = self._checkpoint_fetch(types)
         if self._spec is None or self._genesis_state is None:
             raise ValueError("builder needs a spec and a genesis state")
-        from ..crypto.bls.backends import set_backend
-
-        set_backend(self._bls_backend)  # node assembly selects the device path
-        if self._bls_backend == "jax":
-            # Persistent compile cache + optional AOT bucket warmup
-            # (ops/compile_cache.py): cold XLA compiles are paid once per
-            # binary, not per node restart — and with
-            # LIGHTHOUSE_TPU_AOT_WARMUP=1 the standard buckets compile on a
-            # background thread before the first batch arrives.
-            try:
-                from ..ops import compile_cache
-
-                compile_cache.configure_persistent_cache()
-                compile_cache.maybe_warmup_from_env()
-            except Exception:
-                log.warning("persistent compile-cache setup failed",
-                            exc_info=True)
-            # Mesh sharding (device_mesh.py): LIGHTHOUSE_TPU_MESH=N|auto
-            # shards every bucketed device op's batch axis over the device
-            # mesh.  Configured eagerly at node assembly so the topology
-            # (and its per-device breakers) is logged and gauged before
-            # traffic arrives; <2 devices falls back to single-device
-            # dispatch transparently.
-            try:
-                from .. import device_mesh
-
-                device_mesh.configure()
-            except Exception:
-                log.warning("device mesh setup failed", exc_info=True)
-            # Async device pipeline (device_pipeline.py): production nodes
-            # stream every signature-set group through the persistent device
-            # worker so block import / gossip / sync-committee work coalesce
-            # into maximal device batches.  LIGHTHOUSE_TPU_DEVICE_PIPELINE=0
-            # opts out (device_pipeline.enable honors it).
-            from .. import device_pipeline
-
-            device_pipeline.enable()
-            # Self-tuning control plane (autotune.py): under
-            # LIGHTHOUSE_TPU_AUTOTUNE=live this measures the fq backend
-            # (FQ_BACKEND=auto only; cached per device kind) and starts the
-            # periodic controller that overlays bucket vocabularies from
-            # the flight recorder.  The default mode (pinned) starts
-            # nothing — decisions then replay only from an installed pin.
-            try:
-                from .. import autotune
-
-                autotune.maybe_start_from_env()
-            except Exception:
-                log.warning("autotune startup failed", exc_info=True)
-        if os.environ.get("LIGHTHOUSE_TPU_DEVICE_SHA") == "1":
-            from ..ops.sha256_device import install_device_hash
-
-            install_device_hash()  # bulk Merkle layers on the device VPU
+        assemble_device_path(self._bls_backend)
         if types is None:
             types = build_types(self._spec.preset)
 

@@ -18,12 +18,9 @@ def _device_platform() -> str:
     client call, on every batch)."""
     global _platform
     if _platform is None:
-        try:
-            import jax
+        import jax
 
-            _platform = jax.devices()[0].platform
-        except Exception:
-            _platform = "unknown"
+        _platform = jax.devices()[0].platform
     return _platform
 
 

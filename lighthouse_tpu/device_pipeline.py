@@ -84,6 +84,7 @@ from contextlib import contextmanager
 from typing import Any, Callable, Dict, List, Optional
 
 from . import blackbox, locksmith, metrics, tracing
+from .device_supervisor import PROGRAMMING_ERRORS
 from .logs import get_logger
 from .scheduler.work import STANDARD_DEVICE_BATCH
 
@@ -371,11 +372,12 @@ class _BuiltBatch:
     """One coalesced batch on its way to the executor."""
 
     __slots__ = ("groups", "flat_sets", "built", "unbuilt",
-                 "linger_s", "build_s", "work_mix")
+                 "linger_s", "build_s", "work_mix", "error")
 
     def __init__(self, groups: List[_Group], flat_sets: list, built,
                  unbuilt: bool, linger_s: float, build_s: float,
-                 work_mix: Dict[str, int]):
+                 work_mix: Dict[str, int],
+                 error: Optional[BaseException] = None):
         self.groups = groups
         self.flat_sets = flat_sets
         self.built = built          # ops.verify.BuiltBatch | None (host modes)
@@ -387,6 +389,9 @@ class _BuiltBatch:
         self.linger_s = linger_s
         self.build_s = build_s
         self.work_mix = work_mix
+        #: a programming error raised by the build stage: every group's
+        #: future fails with it instead of resolving on the host
+        self.error = error
 
 
 class DevicePipeline:
@@ -584,6 +589,7 @@ class DevicePipeline:
                 work_mix[g.future.work] = work_mix.get(g.future.work, 0) + len(g.sets)
             built = None
             unbuilt = False
+            error = None
             t0 = time.perf_counter()
             if self._device_mode():
                 try:
@@ -592,17 +598,21 @@ class DevicePipeline:
                         from .ops import verify as verify_mod
 
                         built = verify_mod.build_device_batch(flat)
+                except PROGRAMMING_ERRORS as err:
+                    error = err  # a bug, not a device fault: fail loudly
                 except Exception:
                     # Marshalling itself failed (device OOM mid-upload, a
                     # malformed point, ...): the executor resolves EVERY
                     # group on the host model — a build error must never be
-                    # reported as a bad signature.
+                    # reported as a bad signature.  It is a host fallback
+                    # and counts as one.
+                    metrics.DEVICE_HOST_FALLBACK.inc(reason="build_error")
                     log.warning("pipeline batch build failed; groups resolve "
                                 "on the host model", exc_info=True)
                 unbuilt = built is None
             self._built_q.put(_BuiltBatch(
                 groups, flat, built, unbuilt, linger,
-                time.perf_counter() - t0, work_mix,
+                time.perf_counter() - t0, work_mix, error,
             ))
 
     # ------------------------------------------------------------ executor
@@ -673,6 +683,8 @@ class DevicePipeline:
                     self._cond.notify_all()
 
     def _execute_one(self, batch: _BuiltBatch) -> None:
+        if batch.error is not None:
+            raise batch.error  # _execute_loop fails every group's future
         n_sets = len(batch.flat_sets)
         fill = min(1.0, n_sets / self.target_sets)
         metrics.DEVICE_PIPELINE_BATCHES.inc(op=self.op)

@@ -136,6 +136,13 @@ class HostFallback(Exception):
         self.reason = reason
 
 
+#: Python programming errors: a bug in the device op, not a device fault.
+#: They propagate out of :meth:`DeviceSupervisor.run` untouched (no breaker
+#: charge, no host fallback) — routing them to the host once hid an
+#: ImportError that broke every device epoch call (ROADMAP B1, C4).
+PROGRAMMING_ERRORS = (ImportError, NameError, AttributeError, TypeError)
+
+
 class BreakerConfig:
     """Tuning knobs (see ROBUSTNESS.md), overridable via env."""
 
@@ -440,8 +447,8 @@ class DeviceSupervisor:
                     # dispatch clears every still-closed breaker's counter
                     device_mesh.note_success()
                 return result
-            except HostFallback:
-                raise  # a disclaimer, not a device failure
+            except (HostFallback, *PROGRAMMING_ERRORS):
+                raise  # a disclaimer or a bug, not a device failure
             except DispatchTimeout as err:
                 if meshed and device_mesh.note_failure(
                         "dispatch_timeout", err=err):
@@ -529,6 +536,8 @@ class DeviceSupervisor:
         ``info`` (if given) is filled with route/breaker/fallback details
         for the caller's flight-recorder entry.
 
+        Python programming errors (:data:`PROGRAMMING_ERRORS`) always
+        propagate: the breaker absorbs device and runtime faults only.
         With ``host_fn=None`` failures propagate to the caller —
         :class:`DispatchTimeout` subclasses ``RequeueWork``, so inside a
         scheduler worker the work re-enqueues instead of dropping.
@@ -564,6 +573,8 @@ class DeviceSupervisor:
             if host_fn is None:
                 raise
             return self._host(op, host_fn, "dispatch_timeout", info)
+        except PROGRAMMING_ERRORS:
+            raise
         except Exception as err:
             # Transient device error: one split-batch retry, then host.
             if split_fn is not None:
@@ -601,6 +612,8 @@ class DeviceSupervisor:
                     if host_fn is None:
                         raise
                     return self._host(op, host_fn, "dispatch_timeout", info)
+                except PROGRAMMING_ERRORS:
+                    raise
                 except Exception:
                     metrics.DEVICE_SPLIT_RETRIES.inc(op=op, outcome="failure")
                     info["split_retry"] = "failure"

@@ -8,6 +8,7 @@ library and loaded via ctypes (no pybind11 in the image).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -24,17 +25,22 @@ def _build_dir() -> str:
 
 
 def _compile_and_load(src_name: str, so_name: str, extra_flags=()) -> ctypes.CDLL:
-    """Compile ``src_name`` (if absent or stale) into ``so_name`` and load it."""
+    """Compile ``src_name`` into ``so_name`` (unless already built) and load
+    it.  The library's file name carries a hash of the source bytes and the
+    compiler command, so a library built from other source or flags (a
+    ``build/`` copied along with a checkout) is never loaded."""
     src = os.path.join(os.path.dirname(os.path.abspath(__file__)), src_name)
-    so = os.path.join(_build_dir(), so_name)
-    if not os.path.exists(so) or os.path.getmtime(so) < os.path.getmtime(src):
-        tmp = so + ".tmp"
-        subprocess.run(
-            ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-o", tmp, src,
-             *extra_flags],
-            check=True,
-            capture_output=True,
-        )
+    cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-o", None, src,
+           *extra_flags]
+    h = hashlib.sha256(repr(cmd[:6] + cmd[7:]).encode())
+    with open(src, "rb") as f:
+        h.update(f.read())
+    stem, ext = os.path.splitext(so_name)
+    so = os.path.join(_build_dir(), f"{stem}-{h.hexdigest()[:16]}{ext}")
+    if not os.path.exists(so):
+        tmp = f"{so}.{os.getpid()}.tmp"
+        cmd[6] = tmp
+        subprocess.run(cmd, check=True, capture_output=True)
         os.replace(tmp, so)
     return ctypes.CDLL(so)
 

@@ -254,6 +254,8 @@ class TcpEndpoint:
         self.inbound: "queue.Queue[Envelope]" = queue.Queue()
         self.on_connect: Optional[Callable[[str], None]] = None
         self.on_disconnect: Optional[Callable[[str], None]] = None
+        #: the owning node's telemetry scope, as on ``transport.Endpoint``
+        self.scope = None
         self._conns: Dict[str, socket.socket] = {}
         # peer id -> Noise-proven secp256k1 identity (secured mode): while a
         # connection is LIVE, a second connection claiming its peer id with

@@ -148,22 +148,4 @@ BATCH_AXES = {
                  "NO_SPLIT_OPS; mixed out_batched list (6 per-validator "
                  "outputs + shuffled batched, proposer/found replicated)",
     },
-    "lighthouse_tpu/ops/pallas_fq.py:_fq_mul_pallas_flat": {
-        "op": "pallas_fq_mul",
-        "batch_axis": 0,
-        "batched_args": ["a8p", "b8p"],
-        "replicated_args": [],
-        "reduces_over_batch": False,
-        "out_batched": True,
-        "notes": "bench-only opt-in kernel; tiles of 128 rows",
-    },
-    "lighthouse_tpu/ops/pallas_fq.py:_fq2_mul_pallas_flat": {
-        "op": "pallas_fq2_mul",
-        "batch_axis": 0,
-        "batched_args": ["operands"],
-        "replicated_args": [],
-        "reduces_over_batch": False,
-        "out_batched": True,
-        "notes": "bench-only opt-in kernel; tiles of 128 rows",
-    },
 }

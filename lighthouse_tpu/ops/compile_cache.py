@@ -9,9 +9,10 @@ path (``client.ClientBuilder.build`` / the CLI) had none, so a restarted
 node recompiled everything.  This module is the one shared implementation:
 
 - :func:`configure_persistent_cache` points jax's persistent compile cache
-  at a stable on-disk directory (``LIGHTHOUSE_TPU_COMPILE_CACHE_DIR`` >
-  ``JAX_COMPILATION_CACHE_DIR`` > ``<repo>/.jax_cache``), so cold compiles
-  are paid once per *binary*, not once per process restart.
+  at one stable on-disk directory: ``JAX_COMPILATION_CACHE_DIR`` when the
+  environment sets it (then it is the ONLY cache directory), else
+  ``<repo>/.jax_cache`` — so cold compiles are paid once per *binary*, not
+  once per process restart.
 - :func:`warmup_standard_buckets` ahead-of-time compiles the standard
   dispatch buckets (``jit(...).lower(...).compile()`` on abstract shapes —
   no example batch needed) before traffic arrives, classifying each bucket
@@ -32,7 +33,6 @@ import os
 import threading
 from typing import List, Optional, Sequence, Tuple
 
-CACHE_DIR_ENV = "LIGHTHOUSE_TPU_COMPILE_CACHE_DIR"
 AOT_WARMUP_ENV = "LIGHTHOUSE_TPU_AOT_WARMUP"
 AOT_BUCKETS_ENV = "LIGHTHOUSE_TPU_AOT_BUCKETS"
 
@@ -51,26 +51,20 @@ _REPO_ROOT = os.path.dirname(
 
 
 def default_cache_dir() -> str:
-    return (
-        os.environ.get(CACHE_DIR_ENV)
-        or os.environ.get("JAX_COMPILATION_CACHE_DIR")
-        or os.path.join(_REPO_ROOT, ".jax_cache")
-    )
+    """``JAX_COMPILATION_CACHE_DIR`` if set, else ``<repo>/.jax_cache``."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or os.path.join(_REPO_ROOT, ".jax_cache"))
 
 
-def configure_persistent_cache(cache_dir: Optional[str] = None) -> Optional[str]:
-    """Point jax's persistent compile cache at ``cache_dir`` (default: the
-    env/repo resolution above).  Returns the directory in force, or None if
-    this jax build rejects the config (startup must never fail on a cache).
-    """
+def configure_persistent_cache() -> str:
+    """Point jax's persistent compile cache at :func:`default_cache_dir`
+    and return it.  Raises if jax rejects the config: a node that cannot
+    cache recompiles everything on restart, and must say so."""
     import jax
 
-    path = cache_dir or default_cache_dir()
-    try:
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:
-        return None
+    path = default_cache_dir()
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
     return path
 
 
@@ -124,6 +118,46 @@ def _bucket_shape_structs(nb: int, kb: int):
     wbits = jax.ShapeDtypeStruct((nb, 64), i32)
     live = jax.ShapeDtypeStruct((nb,), np.bool_)
     return pk, sig, msg, wbits, live
+
+
+def kzg_shape_structs(nb: int):
+    """Abstract argument shapes of ``_device_kzg_batch`` for bucket ``nb``
+    (``kzg_device._build_kzg_batch`` marshals exactly these)."""
+    import jax
+    import numpy as np
+
+    i32 = np.int32
+    g1 = tuple(jax.ShapeDtypeStruct((nb, 25), i32) for _ in range(3))
+    bits = jax.ShapeDtypeStruct((nb, 256), i32)
+    g2 = tuple(jax.ShapeDtypeStruct((2, 25), i32) for _ in range(2))
+    return g1, g1, bits, bits, jax.ShapeDtypeStruct((256,), i32), g2, g2
+
+
+def boundary_shape_structs(nb: int):
+    """Abstract argument shapes of ``_boundary_kernel`` for registry bucket
+    ``nb`` at mainnet slots-per-epoch / shuffle rounds.  Build them inside
+    ``jax.enable_x64(True)``, as the dispatch does."""
+    import jax
+    import numpy as np
+
+    from .shuffle_device import PROPOSER_CANDIDATES
+
+    s, r = 32, 90
+    chunks = max(1, (nb + 255) // 256)
+    i64 = jax.ShapeDtypeStruct((nb,), np.int64)
+    return (
+        [i64] * 4
+        + [jax.ShapeDtypeStruct((nb,), np.bool_)]
+        + [i64] * 5
+        + [jax.ShapeDtypeStruct((nb,), np.int32)]
+        + [jax.ShapeDtypeStruct((r,), np.int32),
+           jax.ShapeDtypeStruct((r, chunks * 32), np.uint8),
+           jax.ShapeDtypeStruct((s, 8), np.uint32),
+           jax.ShapeDtypeStruct((s, r), np.int32),
+           jax.ShapeDtypeStruct((s, PROPOSER_CANDIDATES), np.int32)]
+        + [jax.ShapeDtypeStruct((), np.int64)] * 16
+        + [jax.ShapeDtypeStruct((), np.int32)]
+    )
 
 
 def _aot_compile(op: str, shape: Tuple[int, ...], lower_thunk,
@@ -192,13 +226,11 @@ def aot_warmup_op(op: str, nb: int) -> List[dict]:
             "sha256_pairs", (nb,),
             lambda: _sha256_64byte_batch.lower(words).compile())]
     if op in ("epoch_deltas", "epoch_deltas_leak"):
-        from jax.experimental import enable_x64
-
         from .epoch_device import _deltas_kernel
 
         def epoch_thunk(in_leak: bool):
             def thunk():
-                with enable_x64():
+                with jax.enable_x64(True):
                     i64 = jax.ShapeDtypeStruct((nb,), np.int64)
                     s64 = jax.ShapeDtypeStruct((), np.int64)
                     args = ([i64] * 4
@@ -225,12 +257,10 @@ def aot_warmup_op(op: str, nb: int) -> List[dict]:
 
         return [_aot_compile("shuffle", (nb,), shuffle_thunk)]
     if op == "proposer_select":
-        from jax.experimental import enable_x64
-
         from .shuffle_device import PROPOSER_CANDIDATES, _proposer_kernel
 
         def proposer_thunk():
-            with enable_x64():
+            with jax.enable_x64(True):
                 s, r = 32, 90  # mainnet slots-per-epoch / rounds
                 seed_words = jax.ShapeDtypeStruct((s, 8), np.uint32)
                 pivots = jax.ShapeDtypeStruct((s, r), np.int32)
@@ -244,32 +274,13 @@ def aot_warmup_op(op: str, nb: int) -> List[dict]:
 
         return [_aot_compile("proposer_select", (nb,), proposer_thunk)]
     if op in ("epoch_boundary", "epoch_boundary_leak"):
-        from jax.experimental import enable_x64
-
-        from .shuffle_device import PROPOSER_CANDIDATES, _boundary_kernel
+        from .shuffle_device import _boundary_kernel
 
         def boundary_thunk(in_leak: bool):
             def thunk():
-                with enable_x64():
-                    s, r = 32, 90
-                    chunks = max(1, (nb + 255) // 256)
-                    i64 = jax.ShapeDtypeStruct((nb,), np.int64)
-                    args = (
-                        [i64] * 4
-                        + [jax.ShapeDtypeStruct((nb,), np.bool_)]
-                        + [i64] * 5
-                        + [jax.ShapeDtypeStruct((nb,), np.int32)]
-                        + [jax.ShapeDtypeStruct((r,), np.int32),
-                           jax.ShapeDtypeStruct((r, chunks * 32), np.uint8),
-                           jax.ShapeDtypeStruct((s, 8), np.uint32),
-                           jax.ShapeDtypeStruct((s, r), np.int32),
-                           jax.ShapeDtypeStruct(
-                               (s, PROPOSER_CANDIDATES), np.int32)]
-                        + [jax.ShapeDtypeStruct((), np.int64)] * 16
-                        + [jax.ShapeDtypeStruct((), np.int32)]
-                    )
+                with jax.enable_x64(True):
                     _boundary_kernel.lower(
-                        *args, in_leak=in_leak).compile()
+                        *boundary_shape_structs(nb), in_leak=in_leak).compile()
             return thunk
 
         return [

@@ -274,8 +274,6 @@ def epoch_deltas_device(
     sliced back off the outputs."""
     import time as _time
 
-    from jax.experimental import enable_x64
-
     from .. import device_mesh, device_telemetry, fault_injection
 
     # One executable per (registry-bucket, in_leak) pair — in_leak is a
@@ -289,7 +287,7 @@ def epoch_deltas_device(
         if not device_telemetry.COMPILE_CACHE.seen(op, (np_,), mesh=mesh):
             fault_injection.check("device.compile", op=op)
         fault_injection.check("device.dispatch", op=op)
-    with enable_x64():
+    with jax.enable_x64(True):
         batched = (
             np.asarray(arrays.effective_balance, dtype=np.int64),
             np.asarray(arrays.activation_epoch, dtype=np.int64),

@@ -421,8 +421,6 @@ def proposer_select_device(
     walk."""
     import time as _time
 
-    from jax.experimental import enable_x64
-
     from .. import device_telemetry, fault_injection
 
     op = "proposer_select"
@@ -440,7 +438,7 @@ def proposer_select_device(
     eff = np.asarray(effective_balance, dtype=np.int64)
     eff_act = np.zeros(nb, dtype=np.int64)
     eff_act[:m] = eff[active]
-    with enable_x64():
+    with jax.enable_x64(True):
         t_dispatch = _time.perf_counter()
         # recompile-hazard: ok(m is the traced m_live value arg, shapes are bucketed)
         out = _proposer_kernel(
@@ -519,6 +517,78 @@ class BoundaryPlan:
         return int(self.active_idx.shape[0])
 
 
+def synthetic_plan(n: int, seed: int) -> BoundaryPlan:
+    """A mainnet-shaped synthetic BoundaryPlan over ``n`` validators: every
+    registry field the fused kernel reads, with realistic distributions (a
+    few exited / slashed / pending validators, gwei-scale balances), made
+    from ``seed`` in bulk.  Used by ``bench.py --epoch-child`` and
+    ``chip_smoke.py``."""
+    import math
+
+    rng = np.random.default_rng(seed)
+    gwei = 10**9
+    max_eb = 32 * gwei
+    far_future = 2**63 - 1
+    current_epoch = 5
+    eff = (rng.integers(17, 33, size=n).astype(np.int64)) * gwei
+    balance = eff + rng.integers(-2 * gwei, 2 * gwei, size=n)
+    activation_epoch = np.zeros(n, dtype=np.int64)
+    exit_epoch = np.full(n, far_future, dtype=np.int64)
+    withdrawable_epoch = np.full(n, far_future, dtype=np.int64)
+    act_elig = np.zeros(n, dtype=np.int64)
+    # ~1% exited, ~0.5% slashed, ~0.5% still pending activation
+    exited = rng.random(n) < 0.01
+    exit_epoch[exited] = current_epoch - 1
+    withdrawable_epoch[exited] = current_epoch + 200
+    pending = (~exited) & (rng.random(n) < 0.005)
+    activation_epoch[pending] = far_future
+    act_elig[pending] = far_future
+    slashed = (~exited) & (~pending) & (rng.random(n) < 0.005)
+    active = (activation_epoch <= current_epoch + 1) & (
+        current_epoch + 1 < exit_epoch)
+    active_idx = np.nonzero(active)[0].astype(np.int64)
+    total_active = int(eff[active].sum())
+    increment = gwei
+    hyst = increment // 4
+    attester_seed = sha256(b"epoch-bench-att-%d" % seed).digest()
+    slot_seeds = tuple(  # mainnet slots_per_epoch: one proposer per slot
+        sha256(b"epoch-bench-slot-%d-%d" % (seed, s)).digest()
+        for s in range(32))
+    return BoundaryPlan(
+        effective_balance=eff,
+        activation_epoch=activation_epoch,
+        exit_epoch=exit_epoch,
+        withdrawable_epoch=withdrawable_epoch,
+        slashed=slashed,
+        prev_part=rng.integers(0, 8, size=n).astype(np.int64),
+        inactivity=rng.integers(0, 12, size=n).astype(np.int64),
+        balance=balance,
+        activation_eligibility_epoch=act_elig,
+        eb_cap=np.full(n, max_eb, dtype=np.int64),
+        active_idx=active_idx,
+        attester_seed=attester_seed,
+        slot_seeds=slot_seeds,
+        rounds=90,  # mainnet shuffle_round_count
+        previous_epoch=current_epoch - 1,
+        base_reward_per_increment=(
+            increment * 64 // math.isqrt(max(total_active, 1))),
+        total_active_balance=max(total_active, increment),
+        increment=increment,
+        inactivity_score_bias=4,
+        inactivity_score_recovery_rate=16,
+        quotient=2**24,
+        current_epoch=current_epoch,
+        downward=hyst,
+        upward=hyst * 5,
+        ejection_balance=16 * gwei,
+        far_future=far_future,
+        finalized_epoch=current_epoch - 2,
+        max_effective_balance=max_eb,
+        queue_lo=max_eb,
+        queue_hi=max_eb,
+    )
+
+
 def epoch_boundary_device(plan: BoundaryPlan, *, in_leak: bool):
     """numpy in, numpy out — ONE supervised device program for the whole
     epoch boundary.  Returns ``(new_inactivity, balance_delta, new_eff,
@@ -526,8 +596,6 @@ def epoch_boundary_device(plan: BoundaryPlan, *, in_leak: bool):
     found)``; per-validator arrays sliced to ``plan.n``, the shuffling to
     ``plan.m``, proposer/found per slot."""
     import time as _time
-
-    from jax.experimental import enable_x64
 
     from .. import device_mesh, device_telemetry, fault_injection
 
@@ -546,7 +614,7 @@ def epoch_boundary_device(plan: BoundaryPlan, *, in_leak: bool):
         plan.slot_seeds, plan.rounds, m)
     active_padded = np.zeros(np_, dtype=np.int32)
     active_padded[:m] = plan.active_idx.astype(np.int32)
-    with enable_x64():
+    with jax.enable_x64(True):
         batched = (
             np.asarray(plan.effective_balance, dtype=np.int64),
             np.asarray(plan.activation_epoch, dtype=np.int64),

@@ -83,13 +83,6 @@ AXIS_TO_VOCAB = {
     ("kzg_batch", "sets"): "kzg_batch/sets",
 }
 
-#: Registered ops with no bucket vocabulary BY DESIGN: the Pallas kernels
-#: are bench-only opt-ins that tile rows instead of bucketing.  Anything
-#: else registered in batch_axes.py without a readable vocabulary fails
-#: the self-test — a new device entry point must be tunable or exempted
-#: here with a reason.
-VOCABULARY_EXEMPT_OPS = frozenset({"pallas_fq_mul", "pallas_fq2_mul"})
-
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _ROOT = os.path.dirname(os.path.dirname(_HERE))
 _BATCH_AXES_PATH = os.path.join(_ROOT, "lighthouse_tpu", "ops",
@@ -135,15 +128,13 @@ def read_live_vocabularies() -> Tuple[Optional[Dict[str, List[int]]],
     ``N_BUCKETS``/``K_BUCKETS`` literals — suggestions can never go stale
     against the sources.  ``(None, [])`` when the repo sources are absent
     (callers fall back to the committed snapshot); a registered op with no
-    readable vocabulary is an ERROR unless exempted above."""
+    readable vocabulary is an ERROR."""
     modules = _registered_modules()
     if modules is None:
         return None, []
     vocabs: Dict[str, List[int]] = {}
     errors: List[str] = []
     for op, rel in sorted(modules.items()):
-        if op in VOCABULARY_EXEMPT_OPS:
-            continue
         path = os.path.join(_ROOT, rel)
         try:
             with open(path, "r", encoding="utf-8") as f:
@@ -155,8 +146,7 @@ def read_live_vocabularies() -> Tuple[Optional[Dict[str, List[int]]],
         if n_buckets is None:
             errors.append(
                 f"{op}: registered module {rel} declares no N_BUCKETS "
-                "vocabulary — a device entry point must be tunable (or "
-                "exempted in VOCABULARY_EXEMPT_OPS with a reason)")
+                "vocabulary — a device entry point must be tunable")
             continue
         # telemetry spells the leak-mode epoch op separately but both
         # share one registry vocabulary (AXIS_TO_VOCAB folds them)
@@ -340,7 +330,7 @@ def _check_live_vocabularies() -> List[str]:
     """Inside the repo: every batch_axes-registered op must yield a live
     vocabulary (the read_live_vocabularies errors ARE self-test failures —
     a registered device entry point with nothing to tune is either a
-    missing N_BUCKETS or a missing exemption), and the offline fallback
+    missing N_BUCKETS), and the offline fallback
     snapshot must match the live read.  Silently skipped on a bare
     telemetry dump outside the repo."""
     live, read_errors = read_live_vocabularies()

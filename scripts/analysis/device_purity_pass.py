@@ -6,7 +6,7 @@ vanish on subsequent calls (metrics/log/print fire once, not per step),
 capture trace-time values forever (``time.time()``, host randomness), or
 mutate host state from inside a compiled region (cache writes).  64-bit
 dtypes additionally downcast silently to 32-bit unless dispatch is wrapped
-in ``jax.experimental.enable_x64`` — the classic "my balances truncated"
+in ``jax.enable_x64(True)`` — the classic "my balances truncated"
 bug.
 
 Flags, inside jit/Pallas functions:

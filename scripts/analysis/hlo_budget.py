@@ -454,9 +454,7 @@ def measure_target(target: Target) -> Dict[str, int]:
             return counts
 
         if target.op in X64_OPS:
-            from jax.experimental import enable_x64
-
-            with enable_x64():
+            with jax.enable_x64(True):
                 return measure()
         return measure()
     finally:

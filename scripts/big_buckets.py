@@ -29,11 +29,10 @@ os.environ.setdefault("JAX_ENABLE_X64", "0")
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_compilation_cache_dir", os.path.join(HERE, ".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-except Exception:
-    pass
+
+from lighthouse_tpu.ops.compile_cache import configure_persistent_cache  # noqa: E402
+
+configure_persistent_cache()
 
 
 def main() -> None:

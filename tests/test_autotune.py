@@ -292,9 +292,9 @@ class TestMeasuredFqBackend:
         version) next to the compile cache, and fq's ``auto`` resolution
         prefers the measurement over the platform guess — asserted by
         caching int8 on this CPU host, where the guess would say int32."""
-        from lighthouse_tpu.ops import compile_cache, fq
+        from lighthouse_tpu.ops import fq
 
-        monkeypatch.setenv(compile_cache.CACHE_DIR_ENV, str(tmp_path))
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
         calls = []
 
         def fake_probe(backend, rows=512, reps=3):
@@ -328,9 +328,9 @@ class TestMeasuredFqBackend:
         assert any(d["knob"] == "fq_backend" for d in snap["decisions"])
 
     def test_mode_zero_ignores_cache(self, tmp_path, monkeypatch):
-        from lighthouse_tpu.ops import compile_cache, fq
+        from lighthouse_tpu.ops import fq
 
-        monkeypatch.setenv(compile_cache.CACHE_DIR_ENV, str(tmp_path))
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
         monkeypatch.setattr(fq, "measure_backend_seconds",
                             lambda backend, rows=512, reps=3: 0.01)
         autotune.set_mode("live")

@@ -18,18 +18,22 @@ def _restore_cache_dir():
     """Tests point the jax cache at tmp dirs; the suite's shared cache must
     be back in force afterwards or every later compile goes cold."""
     yield
-    cc.configure_persistent_cache(os.environ["JAX_COMPILATION_CACHE_DIR"])
+    cc.configure_persistent_cache()
 
 
 def test_cache_dir_resolution_precedence(monkeypatch, tmp_path):
-    monkeypatch.delenv(cc.CACHE_DIR_ENV, raising=False)
+    # JAX_COMPILATION_CACHE_DIR, when set, is the only cache directory
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jaxdir"))
     assert cc.default_cache_dir() == str(tmp_path / "jaxdir")
-    # the LIGHTHOUSE_TPU override wins over the raw jax env
-    monkeypatch.setenv(cc.CACHE_DIR_ENV, str(tmp_path / "lhdir"))
-    assert cc.default_cache_dir() == str(tmp_path / "lhdir")
-    assert cc.configure_persistent_cache() == str(tmp_path / "lhdir")
-    assert jax.config.jax_compilation_cache_dir == str(tmp_path / "lhdir")
+    assert cc.configure_persistent_cache() == str(tmp_path / "jaxdir")
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path / "jaxdir")
+    # unset: <repo>/.jax_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert cc.default_cache_dir() == os.path.join(repo, ".jax_cache")
+    assert cc.configure_persistent_cache() == os.path.join(repo, ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == os.path.join(
+        repo, ".jax_cache")
 
 
 def test_env_bucket_list_parsing(monkeypatch):

@@ -122,6 +122,42 @@ class TestCircuitBreaker:
         assert metrics.DEVICE_BREAKER_STATE.get(op="t_sse") == 0
 
 
+# ------------------------------------------- programming errors propagate
+
+
+class TestProgrammingErrors:
+    """The breaker absorbs device and runtime faults only: a bug in the
+    device op (here raised inside ``device_fn``) reaches the caller, never
+    the host, and leaves the breaker closed."""
+
+    @pytest.mark.parametrize("exc", [ImportError, TypeError])
+    def test_propagates_without_fallback(self, exc):
+        def device_fn():
+            raise exc("bug in the device op")
+
+        before = sum(metrics.DEVICE_HOST_FALLBACK.snapshot().values())
+        for _ in range(4):  # more than the breaker's failure threshold
+            with pytest.raises(exc, match="bug in the device op"):
+                ds.run("prog_error_probe", device_fn, host_fn=lambda: True,
+                       split_fn=lambda: [device_fn, device_fn])
+        assert ds.breaker_state("prog_error_probe") == "closed"
+        assert sum(metrics.DEVICE_HOST_FALLBACK.snapshot().values()) == before
+
+    def test_injected_device_fault_still_falls_back(self):
+        def device_fn():
+            fi.check("device.dispatch", op="prog_error_probe")
+            return False  # never reached: the fault fires first
+
+        fi.install("device.dispatch", "error", op="prog_error_probe")
+        before = metrics.DEVICE_HOST_FALLBACK.get(reason="device_error")
+        info = {}
+        assert ds.run("prog_error_probe", device_fn, host_fn=lambda: True,
+                      info=info) is True
+        assert info["route"] == "host"
+        assert metrics.DEVICE_HOST_FALLBACK.get(
+            reason="device_error") == before + 1
+
+
 # -------------------------------------------------------- supervised verify
 
 
@@ -231,6 +267,9 @@ class TestSupervisedBlsVerify:
         # a fresh worker serves the next batch on the device
         assert verify_signature_sets_device([s], seed=b"t") is True
         assert ds.SUPERVISOR.breaker("bls_verify").state == "closed"
+        # let the abandoned worker finish its hang: woken inside the next
+        # test, it would fire that test's fault plans
+        time.sleep(max(0.0, t0 + 1.6 - time.perf_counter()))
 
     def test_split_half_disclaimer_is_not_a_breaker_failure(self):
         """A HostFallback raised by a split half (W at infinity) routes to
