@@ -49,6 +49,14 @@ def assemble_device_path(bls_backend: str) -> None:
 
         compile_cache.configure_persistent_cache()
         compile_cache.maybe_warmup_from_env()
+        # Spans on the profiler's clock: while a JAX profiler session runs
+        # (POST /lighthouse/device/profile, a benchmark's traced window),
+        # every tracing span is also a host event beside the device ops.
+        import jax
+
+        from .. import tracing
+
+        tracing.set_profiler_annotation(jax.profiler.TraceAnnotation)
         # Mesh sharding (device_mesh.py): LIGHTHOUSE_TPU_MESH=N|auto
         # shards every bucketed device op's batch axis over the device
         # mesh.  Configured eagerly at node assembly so the topology (and

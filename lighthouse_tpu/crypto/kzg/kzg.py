@@ -31,6 +31,7 @@ from dataclasses import dataclass
 from functools import lru_cache
 from typing import List, Optional, Sequence, Tuple
 
+from ... import tracing
 from ..bls import curve, serde
 from ..bls.fields import Fq, Fq2
 from ..bls.pairing import multi_pairing_is_one
@@ -363,22 +364,36 @@ class Kzg:
     ) -> bool:
         """Batch verify: one random linear combination, one 2-pairing check
         (reference hot path ``crypto/kzg/src/lib.rs:81-107`` →
-        ``c_kzg::KzgProof::verify_blob_kzg_proof_batch``)."""
+        ``c_kzg::KzgProof::verify_blob_kzg_proof_batch``).
+
+        Traced as ``kzg_blob_batch`` (under ``da_check`` when block import
+        calls it).  Its host side, ``kzg_host_prep``, runs in three
+        phases over all blobs: ``kzg_parse`` (bytes to G1 with subgroup
+        checks), ``kzg_challenge`` (blob to polynomial, Fiat-Shamir) and
+        ``kzg_evaluate`` (evaluation at the challenges); then the batch's
+        random powers."""
         if not (len(blobs) == len(commitments) == len(proofs)):
             raise KzgError("length mismatch")
-        if len(blobs) == 0:
-            return True
-        if len(blobs) == 1:
-            return self.verify_blob_kzg_proof(blobs[0], commitments[0], proofs[0])
-        c_pts = [_bytes_to_g1(c) for c in commitments]
-        p_pts = [_bytes_to_g1(p) for p in proofs]
-        zs, ys = [], []
-        for blob, commitment in zip(blobs, commitments):
-            poly = blob_to_polynomial(blob, self.width)
-            challenge = self.compute_challenge(blob, commitment)
-            zs.append(challenge)
-            ys.append(self.evaluate_polynomial_in_evaluation_form(poly, challenge))
-        return self._verify_kzg_proof_batch(c_pts, commitments, zs, ys, p_pts, proofs)
+        with tracing.span("kzg_blob_batch", n_blobs=len(blobs),
+                          device=self.device):
+            if len(blobs) == 0:
+                return True
+            if len(blobs) == 1:
+                return self.verify_blob_kzg_proof(blobs[0], commitments[0],
+                                                  proofs[0])
+            with tracing.span("kzg_host_prep"):
+                with tracing.span("kzg_parse"):
+                    c_pts = [_bytes_to_g1(c) for c in commitments]
+                    p_pts = [_bytes_to_g1(p) for p in proofs]
+                with tracing.span("kzg_challenge"):
+                    polys = [blob_to_polynomial(blob, self.width) for blob in blobs]
+                    zs = [self.compute_challenge(blob, commitment)
+                          for blob, commitment in zip(blobs, commitments)]
+                with tracing.span("kzg_evaluate"):
+                    ys = [self.evaluate_polynomial_in_evaluation_form(poly, z)
+                          for poly, z in zip(polys, zs)]
+                r_powers = self._compute_r_powers(commitments, zs, ys, proofs)
+            return self._verify_kzg_proof_batch(c_pts, zs, ys, p_pts, r_powers)
 
     def _compute_r_powers(
         self,
@@ -399,10 +414,7 @@ class Kzg:
             powers[i] = powers[i - 1] * r % BLS_MODULUS
         return powers
 
-    def _verify_kzg_proof_batch(
-        self, c_pts, commitments_bytes, zs, ys, p_pts, proofs_bytes
-    ) -> bool:
-        r_powers = self._compute_r_powers(commitments_bytes, zs, ys, proofs_bytes)
+    def _verify_kzg_proof_batch(self, c_pts, zs, ys, p_pts, r_powers) -> bool:
         if self.device:
             from ...ops.kzg_device import verify_kzg_proof_batch_device
 

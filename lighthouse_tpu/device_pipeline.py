@@ -361,11 +361,33 @@ class VerifyFuture(_FutureBase):
 
 
 class _Group:
-    __slots__ = ("sets", "future")
+    """One submitted group; ``span`` is the submitter's active span, which
+    the builder and executor threads re-attach (the scheduler's
+    ``WorkEvent`` seam), so a group's batch spans land in its trace."""
 
-    def __init__(self, sets: list, future: VerifyFuture):
+    __slots__ = ("sets", "future", "span")
+
+    def __init__(self, sets: list, future: VerifyFuture, span=None):
         self.sets = sets
         self.future = future
+        self.span = span
+
+
+@contextmanager
+def _in_groups_trace(groups: List[_Group]):
+    """Run the body under the oldest traced group's span; the other groups'
+    traces are named by ``linked_trace_ids``, yielded as span fields."""
+    spans = [g.span for g in groups if g.span is not None]
+    parent = spans[0] if spans else None
+    linked = []
+    for sp in spans[1:]:
+        if sp.trace is not parent.trace and sp.trace.trace_id not in linked:
+            linked.append(sp.trace.trace_id)
+    token = tracing.attach(parent)
+    try:
+        yield {"linked_trace_ids": linked} if linked else {}
+    finally:
+        tracing.detach(token)
 
 
 class _BuiltBatch:
@@ -458,7 +480,7 @@ class DevicePipeline:
         with self._cond:
             if self._shutdown:
                 raise PipelineShutdown(f"{self.op}: pipeline is shut down")
-            self._pending.append(_Group(sets, fut))
+            self._pending.append(_Group(sets, fut, tracing.current_span()))
             self._pending_sets += len(sets)
             self.groups_total += 1
             self.sets_total += len(sets)
@@ -593,8 +615,9 @@ class DevicePipeline:
             t0 = time.perf_counter()
             if self._device_mode():
                 try:
-                    with tracing.span("pipeline_build", n_sets=len(flat),
-                                      n_groups=len(groups)):
+                    with _in_groups_trace(groups) as links, tracing.span(
+                            "pipeline_build", n_sets=len(flat),
+                            n_groups=len(groups), **links):
                         from .ops import verify as verify_mod
 
                         built = verify_mod.build_device_batch(flat)
@@ -690,10 +713,11 @@ class DevicePipeline:
         metrics.DEVICE_PIPELINE_BATCHES.inc(op=self.op)
         metrics.DEVICE_PIPELINE_BATCH_FILL_RATIO.observe(fill, op=self.op)
         metrics.DEVICE_PIPELINE_LINGER_SECONDS.observe(batch.linger_s, op=self.op)
-        with tracing.span(
+        with _in_groups_trace(batch.groups) as links, tracing.span(
             "pipeline_batch", op=self.op, n_sets=n_sets,
             n_groups=len(batch.groups), fill_ratio=round(fill, 4),
             linger_s=round(batch.linger_s, 6), work_mix=dict(batch.work_mix),
+            **links,
         ):
             rechecked = 0
             if batch.unbuilt:

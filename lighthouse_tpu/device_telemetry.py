@@ -42,6 +42,7 @@ stays at zero findings by construction.
 from __future__ import annotations
 
 import os
+import re
 import threading
 import time
 from collections import deque
@@ -422,6 +423,118 @@ def recent_inflight_seconds(op: str, min_samples: int = 3,
         return None
     durations.sort()
     return durations[len(durations) // 2]
+
+
+# ------------------------------------------------------------ program stages
+
+_HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%([^\s=]+)\s*=\s*(.*)$")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+#: The attributes that name called computations; operands precede them.
+_HLO_CALLS = re.compile(r"\b(?:calls|body|condition|to_apply|true_computation|"
+                        r"false_computation|branch_computations)=")
+_HLO_REF = re.compile(r"%([\w.\-]+)")
+
+
+def hlo_stage_map(hlo_text: str, stages) -> Dict[str, str]:
+    """HLO instruction name -> stage, for an optimised HLO module's text.
+
+    An instruction's stage is the innermost of ``stages`` in its ``op_name``
+    metadata (a ``jax.named_scope`` path).  One without it takes the stage
+    of the computations it calls (a fusion, a loop), else of its operands,
+    else of the instruction that calls its computation."""
+    stages = set(stages)
+    comp = None
+    instrs: Dict[str, dict] = {}
+    members: Dict[str, List[str]] = {}
+    for line in hlo_text.splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        if m is None:
+            head = line.strip()
+            if head.endswith("{") and "(" in head:
+                comp = head.split("(", 1)[0].replace("ENTRY", "").strip()
+                comp = comp.lstrip("%")
+                members.setdefault(comp, [])
+            continue
+        name, rest = m.groups()
+        op_name = _HLO_OP_NAME.search(rest)
+        stage = None
+        if op_name:
+            for part in op_name.group(1).split("/"):
+                if part in stages:
+                    stage = part
+        body = rest.split("metadata=", 1)[0]
+        at = _HLO_CALLS.search(body)
+        head, tail = (body[:at.start()], body[at.start():]) if at else (body, "")
+        instrs[name] = {"stage": stage, "comp": comp,
+                        "calls": _HLO_REF.findall(tail),
+                        "operands": _HLO_REF.findall(head)}
+        members.setdefault(comp, []).append(name)
+    caller: Dict[str, str] = {}
+    for name, ins in instrs.items():
+        for c in ins["calls"]:
+            caller.setdefault(c, name)
+
+    def callee_stage(comps, seen):
+        for c in comps:
+            if c in seen:
+                continue
+            seen.add(c)
+            for member in members.get(c, ()):
+                if instrs[member]["stage"]:
+                    return instrs[member]["stage"]
+            for member in members.get(c, ()):
+                found = callee_stage(instrs[member]["calls"], seen)
+                if found:
+                    return found
+        return None
+
+    changed = True
+    while changed:
+        changed = False
+        for name, ins in instrs.items():
+            if ins["stage"]:
+                continue
+            stage = callee_stage(ins["calls"], set())
+            if stage is None:
+                stage = next((instrs[o]["stage"] for o in ins["operands"]
+                              if o in instrs and instrs[o]["stage"]), None)
+            if stage is None and ins["comp"] in caller:
+                stage = instrs[caller[ins["comp"]]]["stage"]
+            if stage:
+                ins["stage"] = stage
+                changed = True
+    return {name: ins["stage"] for name, ins in instrs.items() if ins["stage"]}
+
+
+#: (op, shape) -> its stage map, filled when a reader first asks.
+_STAGE_MAPS: Dict[Tuple[str, Tuple[int, ...]], Dict[str, str]] = {}
+# process-boundary: ok(scope seam: compiled programs are per process)
+_STAGE_MAPS_LOCK = threading.Lock()
+
+
+def program_stages(op: str, shape: Tuple[int, ...]) -> Optional[Dict[str, str]]:
+    """Map each HLO instruction of the single-device ``op`` program compiled
+    at bucket ``shape`` to its stage (the ``STAGES`` scopes of its module),
+    from the ``op_name`` metadata of the executable's optimised HLO.  The
+    names are those a profiler's ``XLA Ops`` events carry.  None for an op
+    without named stages.  Computed when a reader first asks, then cached;
+    never called on the request path."""
+    if op == "bls_verify":
+        from .ops import verify as program
+    elif op == "kzg_batch":
+        from .ops import kzg_device as program
+    else:
+        return None
+    key = (op, tuple(int(s) for s in shape))
+    with _STAGE_MAPS_LOCK:
+        cached = _STAGE_MAPS.get(key)
+    if cached is None:
+        text = program.lower_for_stages(key[1]).compile().as_text()
+        cached = hlo_stage_map(text, program.STAGES)
+        with _STAGE_MAPS_LOCK:
+            # process-boundary: ok(scope seam: compiled programs are per process)
+            _STAGE_MAPS[key] = cached
+    return cached
 
 
 # ------------------------------------------------------------- device memory

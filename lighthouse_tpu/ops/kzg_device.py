@@ -37,27 +37,57 @@ def _device_kzg_batch(c, p, r_bits, rz_bits, ry_bits, tau, g2gen):
     """c, p: G1 projective coords (N, 25) x3 (commitments, proofs);
     r_bits, rz_bits: (N, 256) int32 MSB-first scalars (r_i, r_i*z_i mod R);
     ry_bits: (256,) — sum_i r_i*y_i mod R; tau, g2gen: affine twist (2, 25) x2.
-    Returns the final-exponentiation output limbs (host-checks == 1)."""
-    c_w = ec.scalar_mul_bits(ec.G1_OPS, c, r_bits)       # [r_i] C_i
-    p_w = ec.scalar_mul_bits(ec.G1_OPS, p, r_bits)       # [r_i] P_i
-    pz_w = ec.scalar_mul_bits(ec.G1_OPS, p, rz_bits)     # [r_i z_i] P_i
+    Returns the final-exponentiation output limbs (host-checks == 1).
 
-    proof_lincomb = ec.tree_sum(ec.G1_OPS, p_w, axis=0)
-    c_lincomb = ec.tree_sum(ec.G1_OPS, c_w, axis=0)
-    pz_lincomb = ec.tree_sum(ec.G1_OPS, pz_w, axis=0)
+    Named scopes (HLO metadata only) mark the stages that
+    ``device_telemetry.program_stages`` maps the compiled instructions to:
+    the four bit ladders (``k1_g1_ladders``), the tree sums and adds
+    (``k2_lincomb``), then the pairing's ``miller_loop`` and ``final_exp``.
+    """
+    with jax.named_scope("k1_g1_ladders"):
+        c_w = ec.scalar_mul_bits(ec.G1_OPS, c, r_bits)       # [r_i] C_i
+        p_w = ec.scalar_mul_bits(ec.G1_OPS, p, r_bits)       # [r_i] P_i
+        pz_w = ec.scalar_mul_bits(ec.G1_OPS, p, rz_bits)     # [r_i z_i] P_i
 
-    gen = tuple(jnp.asarray(x) for x in ec.G1_GEN_LIMBS)
-    gen_ry = ec.scalar_mul_bits(ec.G1_OPS, gen, ry_bits)  # [sum r_i y_i] G1
+    with jax.named_scope("k2_lincomb"):
+        proof_lincomb = ec.tree_sum(ec.G1_OPS, p_w, axis=0)
+        c_lincomb = ec.tree_sum(ec.G1_OPS, c_w, axis=0)
+        pz_lincomb = ec.tree_sum(ec.G1_OPS, pz_w, axis=0)
 
-    rhs = ec.point_add(
-        ec.G1_OPS,
-        ec.point_add(ec.G1_OPS, c_lincomb, ec.point_neg(gen_ry)),
-        pz_lincomb,
-    )
-    p1 = tuple(jnp.stack([a, b]) for a, b in zip(proof_lincomb, ec.point_neg(rhs)))
+    with jax.named_scope("k1_g1_ladders"):
+        gen = tuple(jnp.asarray(x) for x in ec.G1_GEN_LIMBS)
+        gen_ry = ec.scalar_mul_bits(ec.G1_OPS, gen, ry_bits)  # [sum r_i y_i] G1
+
+    with jax.named_scope("k2_lincomb"):
+        rhs = ec.point_add(
+            ec.G1_OPS,
+            ec.point_add(ec.G1_OPS, c_lincomb, ec.point_neg(gen_ry)),
+            pz_lincomb,
+        )
+        p1 = tuple(jnp.stack([a, b])
+                   for a, b in zip(proof_lincomb, ec.point_neg(rhs)))
     q2 = tuple(jnp.stack([a, b]) for a, b in zip(tau, g2gen))
     mask = jnp.asarray([True, True])
     return pairing.multi_pairing_fe(p1, q2, mask)
+
+
+#: The named scopes of ``_device_kzg_batch``'s stages, in program order.
+STAGES = ("k1_g1_ladders", "k2_lincomb", "miller_loop", "final_exp")
+
+
+def lower_for_stages(shape):
+    """``_device_kzg_batch`` lowered at the bucket ``(nb,)`` with the
+    dispatched arguments' types, so jit's caches hand back the executable
+    the dispatches ran (``device_telemetry.program_stages``)."""
+    (nb,) = shape
+
+    def i32(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.int32)
+
+    g1 = tuple(i32(nb, 25) for _ in range(3))
+    twist = tuple(i32(2, 25) for _ in range(2))
+    return _device_kzg_batch.lower(g1, g1, i32(nb, 256), i32(nb, 256),
+                                   i32(256), twist, twist)
 
 
 def _bucket(n: int) -> int:
@@ -156,15 +186,17 @@ def verify_kzg_proof_batch_device(
     host MSM golden model in ``crypto/kzg/kzg.py``) under the one shared
     breaker/fallback mechanism.  With ``host_fn=None`` failures propagate.
     """
-    from .. import device_mesh, device_supervisor, device_telemetry, fault_injection
+    from .. import (device_mesh, device_supervisor, device_telemetry,
+                    fault_injection, tracing)
 
     n = len(c_pts)
     assert n == len(p_pts) == len(r_powers) == len(zs) == len(ys)
     holder: dict = {}
 
     def device_fn() -> bool:
-        import time as _time
-
+        # Runs on the supervisor's worker with the caller's span attached:
+        # the kzg_batch_* spans land in the caller's trace, and their
+        # durations are the flight record's stages.
         stages_local: dict = {}
         state_local: dict = {}
         try:
@@ -174,34 +206,39 @@ def verify_kzg_proof_batch_device(
             # the host fallback like any other device failure.  Bucket and
             # mesh pad are (re)computed here too, so a supervisor reshard
             # retry re-places under the surviving topology.
-            t_setup = _time.perf_counter()
-            mesh = device_mesh.size() if device_mesh.enabled() else 0
-            nb = _bucket(max(1, n))
-            if mesh:
-                nb = device_mesh.pad_rows(nb)
-            state_local["mesh"], state_local["nb"] = mesh, nb
-            batch = _build_kzg_batch(c_pts, p_pts, r_powers, zs, ys,
-                                     g2_tau, nb)
-            stages_local["setup"] = _time.perf_counter() - t_setup
+            with tracing.span("kzg_batch_setup", n_blobs=n) as sp_setup:
+                mesh = device_mesh.size() if device_mesh.enabled() else 0
+                nb = _bucket(max(1, n))
+                if mesh:
+                    nb = device_mesh.pad_rows(nb)
+                state_local["mesh"], state_local["nb"] = mesh, nb
+                batch = _build_kzg_batch(c_pts, p_pts, r_powers, zs, ys,
+                                         g2_tau, nb)
+            stages_local["setup"] = sp_setup.duration
             if fault_injection.ACTIVE:
                 if not device_telemetry.COMPILE_CACHE.seen("kzg_batch", (nb,),
                                                            mesh=mesh):
                     fault_injection.check("device.compile", op="kzg_batch")
                 fault_injection.check("device.dispatch", op="kzg_batch")
-            t_dispatch = _time.perf_counter()
-            if mesh:
-                fe = _sharded_entry()(*batch)
-            else:
-                fe = _device_kzg_batch(*batch)
-            dispatch_s = _time.perf_counter() - t_dispatch
-            stages_local["dispatch"] = dispatch_s
-            if device_telemetry.note_dispatch("kzg_batch", (nb,), dispatch_s,
-                                              mesh=mesh):
+            with tracing.span("kzg_batch_dispatch", n_bucket=nb,
+                              mesh=mesh) as sp_dispatch:
+                if mesh:
+                    fe = _sharded_entry()(*batch)
+                else:
+                    fe = _device_kzg_batch(*batch)
+            stages_local["dispatch"] = sp_dispatch.duration
+            if device_telemetry.note_dispatch("kzg_batch", (nb,),
+                                              sp_dispatch.duration, mesh=mesh):
+                sp_dispatch.fields["compiled"] = True
                 state_local["compiled"] = True
-            t_wait = _time.perf_counter()
-            jax.block_until_ready(fe)
-            stages_local["wait"] = _time.perf_counter() - t_wait
-            return pairing.fe_is_one(fe)
+            with tracing.span("kzg_batch_wait", n_bucket=nb) as sp_wait:
+                jax.block_until_ready(fe)
+            stages_local["wait"] = sp_wait.duration
+            with tracing.span("kzg_batch_verdict") as sp_verdict:
+                try:
+                    return pairing.fe_is_one(fe)
+                finally:
+                    stages_local["verdict"] = sp_verdict.duration
         finally:
             holder["stages"] = stages_local
             holder["state"] = state_local

@@ -311,8 +311,12 @@ def multi_pairing_fe(p1, q2, mask):
         f, t = jax.lax.cond(bit.astype(bool), do_add, lambda ft: ft, (f, t))
         return (f, t), None
 
-    (f, _), _ = jax.lax.scan(body, (f0, t0), _X_BITS)
-    return final_exponentiation(f)
+    # Named scopes (HLO metadata only) for device_telemetry.program_stages:
+    # every program that pairs shares these two stages.
+    with jax.named_scope("miller_loop"):
+        (f, _), _ = jax.lax.scan(body, (f0, t0), _X_BITS)
+    with jax.named_scope("final_exp"):
+        return final_exponentiation(f)
 
 
 # ------------------------------------------------------------ host-side check

@@ -100,19 +100,26 @@ def _device_verify(pk, sig, msg, wbits, live):
     live: (N,) bool
     Returns (fe, w_z): final-exponentiation output (12-coeff limbs) and the
     Z coordinate of W = sum_i [r_i] sig_i for the host-side infinity check.
+
+    The stages carry named scopes (HLO metadata only), which
+    ``device_telemetry.program_stages`` maps the compiled instructions to:
+    s1-s3 here, ``miller_loop`` and ``final_exp`` (s4, s5) in the pairing.
     """
-    agg = ec.tree_sum(ec.G1_OPS, pk, axis=1)                  # (N,) G1 proj
+    with jax.named_scope("s1_key_sum"):
+        agg = ec.tree_sum(ec.G1_OPS, pk, axis=1)              # (N,) G1 proj
     # [r_i] aggpk_i stays per-set (each feeds its own pairing); windowed
     # ladder instead of double-and-add (VERDICT r3 item 2).
-    p_weighted = ec.scalar_mul_windowed(ec.G1_OPS, agg, wbits)
+    with jax.named_scope("s2_g1_ladder"):
+        p_weighted = ec.scalar_mul_windowed(ec.G1_OPS, agg, wbits)
     # W = sum_i [r_i] sig_i is ONE multi-scalar multiplication — the shared
     # windowed ladder does ~4x fewer G2 group ops than per-set ladders
     # followed by a tree-sum (blst.rs:112-114 computes this same sum).
-    w = ec.msm_windowed(ec.G2_OPS, sig, wbits)                # G2 proj
-
-    # W -> affine (zero-divides yield exact 0 limbs, caught by the host check).
-    zi = tower.fq2_inv(w[2])
-    w_aff = (tower.fq2_mul(w[0], zi), tower.fq2_mul(w[1], zi))
+    with jax.named_scope("s3_g2_msm"):
+        w = ec.msm_windowed(ec.G2_OPS, sig, wbits)            # G2 proj
+        # W -> affine (zero-divides yield exact 0 limbs, caught by the host
+        # check).
+        zi = tower.fq2_inv(w[2])
+        w_aff = (tower.fq2_mul(w[0], zi), tower.fq2_mul(w[1], zi))
 
     # Assemble N+1 pairs: ( [r_i]aggpk_i, H_i ) ... ( -g1, W ).
     def cat(a, b):
@@ -125,15 +132,41 @@ def _device_verify(pk, sig, msg, wbits, live):
     return fe, w[2]
 
 
+#: The named scopes of ``_device_verify``'s stages s1-s5, in program order.
+STAGES = ("s1_key_sum", "s2_g1_ladder", "s3_g2_msm", "miller_loop", "final_exp")
+
+
+def lower_for_stages(shape: Tuple[int, int]):
+    """``_device_verify`` lowered at the bucket ``(nb, kb)`` with the
+    dispatched arguments' types, so jit's caches hand back the executable
+    the dispatches ran (``device_telemetry.program_stages``)."""
+    nb, kb = shape
+
+    def limbs(*dims):
+        return jax.ShapeDtypeStruct(dims + (25,), jnp.int32)
+
+    return _device_verify.lower(
+        tuple(limbs(nb, kb) for _ in range(3)),
+        tuple(limbs(nb, 2) for _ in range(3)),
+        tuple(limbs(nb, 2) for _ in range(2)),
+        jax.ShapeDtypeStruct((nb, 64), jnp.int32),
+        jax.ShapeDtypeStruct((nb,), jnp.bool_),
+    )
+
+
 # --------------------------------------------------------------- host driver
 
 _hash_cache: dict = {}
 
 
-def _hash_to_g2_cached(message: bytes):
+def _hash_to_g2_cached(message: bytes, misses: Optional[list] = None):
+    """``hash_to_g2`` behind a bounded cache; a miss appends the message to
+    ``misses`` when given (the marshal span's ``n_hash_misses``)."""
     key = bytes(message)
     pt = _hash_cache.get(key)
     if pt is None:
+        if misses is not None:
+            misses.append(key)
         pt = hash_to_g2(key, DST)
         if len(_hash_cache) > 4096:
             _hash_cache.clear()
@@ -220,7 +253,14 @@ def build_batch(sets, rands) -> Optional[tuple]:
     that dispatch these straight into ``_device_verify`` still work.
 
     Returns None if host-side validation already decides False.
+
+    Three contiguous phases, each a span under the caller's
+    ``device_batch_setup``: every signature's checks before any hashing
+    (``marshal_subgroup``), every message's hash (``marshal_hash_to_g2``),
+    then the limbs of all sets (``marshal_pack``).
     """
+    from .. import tracing
+
     n = len(sets)
     # The set axis buckets against the LIVE vocabulary (static N_BUCKETS
     # plus any controller-adopted overlay buckets); the key axis stays
@@ -228,38 +268,47 @@ def build_batch(sets, rands) -> Optional[tuple]:
     nb = _bucket(n, autotune.bucket_vocabulary("bls_verify", N_BUCKETS))
     kb = _bucket(max(len(s.signing_keys) for s in sets), K_BUCKETS)
 
-    pk = [np.zeros((nb, kb, 25), np.int32) for _ in range(3)]
-    sig = [np.zeros((nb, 2, 25), np.int32) for _ in range(3)]
-    msg = [np.zeros((nb, 2, 25), np.int32) for _ in range(2)]
-    wbits = np.zeros((nb, 64), np.int32)
-    live = np.zeros((nb,), bool)
+    with tracing.span("marshal_subgroup", n_sets=n):
+        for s in sets:
+            sig_pt = s.signature.point
+            if sig_pt is None or not curve.in_g2(sig_pt):
+                return None
+            if not s.signing_keys:
+                return None
 
-    id1 = ec.g1_to_limbs(None)
-    id2 = ec.g2_to_limbs(None)
-    for c in range(3):
-        pk[c][:] = id1[c]
-        sig[c][:] = id2[c]
-    for c in range(2):
-        msg[c][:] = _G2_GEN_AFF[c]
+    with tracing.span("marshal_hash_to_g2", n_messages=n) as sp_hash:
+        misses: list = []
+        hashes = [_hash_to_g2_cached(s.message, misses) for s in sets]
+        sp_hash.fields["n_hash_misses"] = len(misses)
 
-    for i, (s, r) in enumerate(zip(sets, rands)):
-        sig_pt = s.signature.point
-        if sig_pt is None or not curve.in_g2(sig_pt):
-            return None
-        if not s.signing_keys:
-            return None
-        sl = ec.g2_to_limbs(sig_pt)
-        h = _hash_to_g2_cached(s.message)
+    n_keys = sum(len(s.signing_keys) for s in sets)
+    with tracing.span("marshal_pack", n_keys=n_keys):
+        pk = [np.zeros((nb, kb, 25), np.int32) for _ in range(3)]
+        sig = [np.zeros((nb, 2, 25), np.int32) for _ in range(3)]
+        msg = [np.zeros((nb, 2, 25), np.int32) for _ in range(2)]
+        wbits = np.zeros((nb, 64), np.int32)
+        live = np.zeros((nb,), bool)
+
+        id1 = ec.g1_to_limbs(None)
+        id2 = ec.g2_to_limbs(None)
         for c in range(3):
-            sig[c][i] = sl[c]
-        msg[0][i] = tower.fq2_to_limbs(h[0])
-        msg[1][i] = tower.fq2_to_limbs(h[1])
-        for j, key in enumerate(s.signing_keys):
-            kl = ec.g1_to_limbs(key.point)
+            pk[c][:] = id1[c]
+            sig[c][:] = id2[c]
+        for c in range(2):
+            msg[c][:] = _G2_GEN_AFF[c]
+
+        for i, (s, h, r) in enumerate(zip(sets, hashes, rands)):
+            sl = ec.g2_to_limbs(s.signature.point)
             for c in range(3):
-                pk[c][i, j] = kl[c]
-        wbits[i] = ec.bits_msb(r, 64)
-        live[i] = True
+                sig[c][i] = sl[c]
+            msg[0][i] = tower.fq2_to_limbs(h[0])
+            msg[1][i] = tower.fq2_to_limbs(h[1])
+            for j, key in enumerate(s.signing_keys):
+                kl = ec.g1_to_limbs(key.point)
+                for c in range(3):
+                    pk[c][i, j] = kl[c]
+            wbits[i] = ec.bits_msb(r, 64)
+            live[i] = True
 
     return tuple(pk), tuple(sig), tuple(msg), wbits, live
 
@@ -381,10 +430,13 @@ class BuiltBatch:
                  "generation", "live_keys", "setup_s")
 
     def __init__(self, sets, seed, host_batch, setup_s: float):
+        from .. import tracing
+
         self.sets = sets
         self.seed = seed
         self.host = host_batch
-        self.batch, self.mesh, self.generation = place_batch(host_batch)
+        with tracing.span("marshal_upload"):
+            self.batch, self.mesh, self.generation = place_batch(host_batch)
         self.nb = int(self.batch[0][0].shape[0])
         self.kb = int(self.batch[0][0].shape[1])
         self.live_keys = sum(len(s.signing_keys) for s in sets)
@@ -403,7 +455,8 @@ class BuiltBatch:
 
 def build_device_batch(sets, seed: Optional[bytes] = None) -> Optional[BuiltBatch]:
     """Stage 1 — host-side marshalling (validation, hash-to-curve, limb
-    packing) into padded device arrays.  Returns None when host-side
+    packing; the ``marshal_*`` spans of :func:`build_batch`, then
+    ``marshal_upload``) into padded device arrays.  Returns None when host-side
     validation already decides False (bad/missing signature, empty key
     list).  Safe to call from any thread; no device work happens here
     beyond the host→device array uploads."""

@@ -29,6 +29,16 @@ A parent may close before a late child does (a delayed re-processed event
 whose originating request already returned).  The child still attaches — the
 tree is serialized at read time — it just renders past its parent's end.
 
+Profiler clock: the device path's assembly (``client.assemble_device_path``)
+installs ``jax.profiler.TraceAnnotation`` through
+:func:`set_profiler_annotation`, and every :func:`span` then also enters an
+annotation of its own name on its thread.  It costs about a microsecond a
+span while no profiler session runs; while one does, the program's
+spans land in the ``.xplane.pb`` host plane beside the device ops, on their
+clock.  Intervals added after the fact (:func:`record_span`, such as
+``pipeline_wait``) cannot be written into a running session and appear only
+in the span tree.  This module never imports JAX itself.
+
 HTTP surface (``http_api/server.py``): ``/lighthouse/traces`` lists recent
 trace summaries; ``/lighthouse/traces/{trace_id}`` returns the full tree,
 ``?format=chrome`` as Chrome trace-event JSON loadable in Perfetto.
@@ -52,6 +62,16 @@ _current: "contextvars.ContextVar[Optional[Span]]" = contextvars.ContextVar(
     "lighthouse_tpu_current_span", default=None
 )
 _seq = itertools.count(1)
+
+#: ``name -> context manager`` entered around every span: the profiler's
+#: host-event annotation once installed, else None.
+_profiler_annotation = None
+
+
+def set_profiler_annotation(factory) -> None:
+    """Enter ``factory(name)`` around every span from now on (None: stop)."""
+    global _profiler_annotation
+    _profiler_annotation = factory
 
 
 def _new_trace_id() -> str:
@@ -195,8 +215,13 @@ def span(name: str, hist=None, hist_labels: Optional[dict] = None, **fields):
     """Record a span; on close, observe its duration into ``hist`` too.
 
     With no active parent this starts a new trace, completed (and pushed to
-    :data:`TRACES`) when the span exits.
+    :data:`TRACES`) when the span exits.  The profiler annotation, once
+    installed, encloses the span.
     """
+    annotation = _profiler_annotation
+    if annotation is not None:
+        annotation = annotation(name)
+        annotation.__enter__()
     parent = _current.get()
     if parent is None:
         trace = Trace(name, fields)
@@ -210,6 +235,8 @@ def span(name: str, hist=None, hist_labels: Optional[dict] = None, **fields):
     finally:
         _current.reset(token)
         sp.close()
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
         if hist is not None:
             hist.observe(sp.duration, **(hist_labels or {}))
         if sp.parent is None:

@@ -355,6 +355,75 @@ class TestTelemetry:
         assert rec["work_mix"]["block_import"] == 4
 
 
+# ------------------------------------------------ one trace per request
+
+
+def _tree(sp, parent=None, out=None):
+    """span name -> [(span, parent name)], over the whole tree."""
+    out = {} if out is None else out
+    out.setdefault(sp.name, []).append((sp, parent))
+    for child in sp.children:
+        _tree(child, sp.name, out)
+    return out
+
+
+class TestOneTracePerRequest:
+    def test_pipeline_verify_yields_one_tree(self):
+        """The pipeline twin of test_device_chain's stage test: a block's
+        verify_signature_sets through the pipeline is ONE trace, holding the
+        caller's wait, the builder's marshal and the executor's device legs."""
+        from lighthouse_tpu import tracing
+
+        sets = _sets(2, seed=31)
+        set_backend("jax")
+        device_pipeline.enable()
+        tracing.TRACES.clear()
+        assert api.verify_signature_sets(sets) is True
+        device_pipeline.get_pipeline().wait_idle(30.0)
+        roots = {t.root.name for t in tracing.TRACES.recent(limit=100)}
+        assert "pipeline_build" not in roots and "pipeline_batch" not in roots
+        trace = tracing.TRACES.recent(root="device_batch")[0]
+        tree = _tree(trace.root)
+        assert tree["pipeline_wait"][0][1] == "device_batch"
+        assert tree["pipeline_build"][0][1] == "device_batch"
+        assert tree["device_batch_setup"][0][1] == "pipeline_build"
+        setup = tree["device_batch_setup"][0][0]
+        assert [c.name for c in setup.children] == [
+            "marshal_subgroup", "marshal_hash_to_g2", "marshal_pack",
+            "marshal_upload"]
+        assert tree["pipeline_batch"][0][1] == "device_batch"
+        for leg in ("device_batch_dispatch", "device_batch_wait",
+                    "device_batch_verdict"):
+            assert tree[leg][0][1] == "pipeline_batch", leg
+        # the batch's flight record names the block's trace
+        from lighthouse_tpu import device_telemetry
+
+        rec = device_telemetry.FLIGHT_RECORDER.recent(op="bls_verify", limit=1)[0]
+        assert rec["trace_id"] == trace.trace_id
+
+    def test_coalesced_groups_link_their_traces(self):
+        """Two groups from two traces in one batch: the batch span opens in
+        the oldest group's trace and names the other's."""
+        from lighthouse_tpu import tracing
+
+        pipe = DevicePipeline("bls_verify", target_sets=2, linger_s=5.0,
+                              verify_flat_fn=lambda s: True)
+        try:
+            with tracing.span("unit_first") as first:
+                f1 = pipe.submit(["a"])
+            with tracing.span("unit_second") as second:
+                f2 = pipe.submit(["b"])
+            assert f1.result(10.0) is True and f2.result(10.0) is True
+            assert pipe.wait_idle(10.0)
+        finally:
+            pipe.shutdown()
+        batch = [c for c in first.children if c.name == "pipeline_batch"]
+        assert len(batch) == 1
+        assert batch[0].fields["n_groups"] == 2
+        assert batch[0].fields["linked_trace_ids"] == [second.trace.trace_id]
+        assert not [c for c in second.children if c.name == "pipeline_batch"]
+
+
 # ----------------------------------------- hash pipeline (ISSUE 13)
 
 

@@ -400,3 +400,64 @@ class TestSseDropAccounting:
         finally:
             server.stop()
             set_backend("host")
+
+
+class TestProgramStages:
+    def test_two_scope_program_maps_every_instruction_to_its_scope(self):
+        """Each instruction of a compiled two-scope program is mapped to its
+        scope: compute by its own metadata, a loop's body and condition by
+        the loop, an unlabelled fusion by its operands."""
+        import re
+
+        import jax
+        import jax.numpy as jnp
+
+        @jax.jit
+        def two_scopes(x, y):
+            with jax.named_scope("st_first"):
+                a = jnp.sin(x) @ y
+                a = jax.lax.fori_loop(0, 40, lambda i, c: jnp.cos(c) * 2 + c, a)
+            with jax.named_scope("st_second"):
+                return jnp.tanh(a).sum(axis=0) * 3
+
+        x = jnp.ones((32, 32))
+        text = two_scopes.lower(x, x).compile().as_text()
+        stages = device_telemetry.hlo_stage_map(text, ("st_first", "st_second"))
+        assert set(stages.values()) == {"st_first", "st_second"}
+        entry = text[text.index("ENTRY"):]
+        ops = re.findall(r"^\s*(?:ROOT\s+)?%(\S+) = .*?\s([a-z][\w-]*)\(",
+                         entry, re.M)
+        compute = [(n, op) for n, op in ops if op not in (
+            "parameter", "constant", "tuple", "get-tuple-element", "copy")]
+        assert compute and all(n in stages for n, _ in compute), compute
+        by_op = {op: stages[n] for n, op in compute}
+        assert by_op["while"] == "st_first" and by_op["dot"] == "st_first"
+        # every instruction named in the map is one of the program's
+        names = set(re.findall(r"^\s*(?:ROOT\s+)?%(\S+) = ", text, re.M))
+        assert set(stages) <= names
+
+    def test_program_stages_reads_the_dispatched_executable(self):
+        """The map of a dispatched bucket comes from jit's caches (no new
+        backend compile), is cached, and names the verifier's stages."""
+        import jax
+
+        from lighthouse_tpu.ops.verify import verify_signature_sets_device
+
+        compiles = []
+
+        def hear(event, duration, **kw):
+            if event.endswith("backend_compile_duration"):
+                compiles.append(event)
+
+        s = make_set(b"stage-map", n_keys=2)
+        assert verify_signature_sets_device([s, s], seed=b"t") is True
+        jax.monitoring.register_event_duration_secs_listener(hear)
+        try:
+            stages = device_telemetry.program_stages("bls_verify", (2, 2))
+        finally:
+            jax.monitoring.unregister_event_duration_listener(hear)
+        assert compiles == []
+        assert {"s1_key_sum", "s2_g1_ladder", "s3_g2_msm", "miller_loop",
+                "final_exp"} == set(stages.values())
+        assert device_telemetry.program_stages("bls_verify", (2, 2)) is stages
+        assert device_telemetry.program_stages("epoch_deltas", (8,)) is None

@@ -168,3 +168,31 @@ class TestValidation:
         proof, y = kzg.compute_kzg_proof(blob, bls_field_to_bytes(5))
         with pytest.raises(KzgError):
             kzg.verify_kzg_proof(commitment, b"\x01" * 31, y, proof)
+
+
+class TestSpans:
+    def test_device_batch_is_one_tree_with_its_stages(self, kzg):
+        """A DA check's batch is one span tree under ``da_check``: the host
+        side in three phases, then the supervised device leg's four spans,
+        whose durations still fill the flight record's stages."""
+        from lighthouse_tpu import device_telemetry, tracing
+
+        blobs = [make_blob(40 + i) for i in range(2)]
+        commitments = [kzg.blob_to_kzg_commitment(b) for b in blobs]
+        proofs = [kzg.compute_blob_kzg_proof(b, c)
+                  for b, c in zip(blobs, commitments)]
+        device = Kzg(kzg.setup, device=True)
+        with tracing.span("da_check") as root:
+            assert device.verify_blob_kzg_proof_batch(blobs, commitments, proofs)
+        (batch,) = root.children
+        assert batch.name == "kzg_blob_batch" and batch.fields["n_blobs"] == 2
+        names = [c.name for c in batch.children]
+        assert names == ["kzg_host_prep", "kzg_batch_setup", "kzg_batch_dispatch",
+                         "kzg_batch_wait", "kzg_batch_verdict"]
+        assert [c.name for c in batch.children[0].children] == [
+            "kzg_parse", "kzg_challenge", "kzg_evaluate"]
+        assert batch.children[1].fields["n_blobs"] == 2
+        rec = device_telemetry.FLIGHT_RECORDER.recent(op="kzg_batch", limit=1)[0]
+        assert rec["trace_id"] == root.trace.trace_id
+        assert set(rec["stages_s"]) == {"setup", "dispatch", "wait", "verdict"}
+        assert rec["stages_s"]["setup"] == round(batch.children[1].duration, 6)

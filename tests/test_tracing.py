@@ -51,6 +51,41 @@ class TestSpans:
             pass
         assert hist.stats()[0] == before + 1
 
+    def test_span_is_a_host_event_on_the_profilers_clock(self, tmp_path):
+        """With the profiler annotation installed (as the device path's
+        assembly does), a span around a jitted call is a host event of its
+        name in the profiler's trace, enclosing the call's own event."""
+        import glob
+
+        import jax
+        import jax.numpy as jnp
+        from jax.profiler import ProfileData
+
+        @jax.jit
+        def unit_profiled(x):
+            return jnp.sin(x) * 2
+
+        x = jnp.ones((64,))
+        unit_profiled(x).block_until_ready()  # compile outside the window
+        tracing.set_profiler_annotation(jax.profiler.TraceAnnotation)
+        try:
+            with jax.profiler.trace(str(tmp_path)):
+                with tracing.span("unit_profiled_span"):
+                    unit_profiled(x).block_until_ready()
+        finally:
+            tracing.set_profiler_annotation(None)
+        (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                                "*.xplane.pb"))
+        events = {}
+        for plane in ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                for ev in line.events:
+                    events.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns))
+        (span,) = events["unit_profiled_span"]
+        calls = events["PjitFunction(unit_profiled)"]
+        assert calls and all(span[0] <= s and e <= span[1] for s, e in calls)
+
     def test_annotate_and_nested_dict(self):
         with tracing.span("outer") as sp:
             tracing.annotate(root="0xabcd")
