@@ -300,6 +300,36 @@ def g2_to_limbs(pt) -> tuple:
     return (_tw.fq2_to_limbs(x), _tw.fq2_to_limbs(y), _tw.fq2_to_limbs(one))
 
 
+#: Bytes of one base-field element in limbs: 25 limbs of 16 bits.
+_FQ_BYTES = 2 * _fq.L16
+
+
+def _limbs_of_bytes(buf: bytes, shape: tuple) -> np.ndarray:
+    """Joined 50-byte little-endian ints -> int32 limbs, ``shape + (25,)``:
+    the ``to_limbs16`` of each.  ``int.to_bytes`` raised on a negative value
+    or one past 400 bits, so nothing was silently cut."""
+    return np.frombuffer(buf, "<u2").reshape(shape + (_fq.L16,)).astype(np.int32)
+
+
+def g1_batch_to_limbs(points) -> tuple:
+    """Host affine G1 points (none at infinity) -> (x, y), each (n, 25):
+    the first two coordinates of ``g1_to_limbs`` of every point (z is 1).
+    One pass over the points: a block's keys lie scattered in memory."""
+    buf = b"".join([x.n.to_bytes(_FQ_BYTES, "little")
+                    + y.n.to_bytes(_FQ_BYTES, "little") for x, y in points])
+    xy = _limbs_of_bytes(buf, (-1, 2))
+    return xy[:, 0], xy[:, 1]
+
+
+def g2_batch_to_limbs(points) -> tuple:
+    """Host affine G2 points (none at infinity) -> (x, y), each (n, 2, 25):
+    the first two coordinates of ``g2_to_limbs`` of every point (z is 1)."""
+    buf = b"".join([v.to_bytes(_FQ_BYTES, "little")
+                    for pt in points for c in pt for v in (c.c0, c.c1)])
+    xy = _limbs_of_bytes(buf, (-1, 2, 2))
+    return xy[:, 0], xy[:, 1]
+
+
 def g1_from_limbs(p):
     """Projective limbs -> host affine golden-model point (exact, host-side)."""
     x = _fq.from_limbs16(np.asarray(p[0]))
@@ -323,6 +353,15 @@ def g2_from_limbs(p):
 
 def bits_msb(k: int, nbits: int) -> np.ndarray:
     return np.array([(k >> (nbits - 1 - i)) & 1 for i in range(nbits)], np.int32)
+
+
+def bits_msb_batch(ks, nbits: int) -> np.ndarray:
+    """``bits_msb(k, nbits)`` of every k in ``ks``, as rows of (n, nbits)."""
+    nbytes = -(-nbits // 8)
+    mask = (1 << nbits) - 1
+    buf = b"".join([(k & mask).to_bytes(nbytes, "big") for k in ks])
+    bits = np.unpackbits(np.frombuffer(buf, np.uint8).reshape(-1, nbytes), axis=1)
+    return bits[:, 8 * nbytes - nbits:].astype(np.int32)
 
 
 G1_GEN_LIMBS = g1_to_limbs((hf.Fq(G1_X), hf.Fq(G1_Y)))

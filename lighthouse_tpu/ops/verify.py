@@ -289,26 +289,31 @@ def build_batch(sets, rands) -> Optional[tuple]:
         wbits = np.zeros((nb, 64), np.int32)
         live = np.zeros((nb,), bool)
 
+        # Identity padding (0, 1, 0): the zeros already hold x and z.
         id1 = ec.g1_to_limbs(None)
         id2 = ec.g2_to_limbs(None)
+        pk[1][:] = id1[1]
         for c in range(3):
-            pk[c][:] = id1[c]
             sig[c][:] = id2[c]
         for c in range(2):
             msg[c][:] = _G2_GEN_AFF[c]
 
-        for i, (s, h, r) in enumerate(zip(sets, hashes, rands)):
-            sl = ec.g2_to_limbs(s.signature.point)
-            for c in range(3):
-                sig[c][i] = sl[c]
-            msg[0][i] = tower.fq2_to_limbs(h[0])
-            msg[1][i] = tower.fq2_to_limbs(h[1])
-            for j, key in enumerate(s.signing_keys):
-                kl = ec.g1_to_limbs(key.point)
-                for c in range(3):
-                    pk[c][i, j] = kl[c]
-            wbits[i] = ec.bits_msb(r, 64)
-            live[i] = True
+        # Key j of set i goes to row i, column j: one scatter for all keys.
+        counts = np.fromiter((len(s.signing_keys) for s in sets), np.intp, n)
+        rows = np.repeat(np.arange(n), counts)
+        cols = np.arange(n_keys) - np.repeat(np.cumsum(counts) - counts, counts)
+        kx, ky = ec.g1_batch_to_limbs(
+            [key.point for s in sets for key in s.signing_keys])
+        pk[0][rows, cols] = kx
+        pk[1][rows, cols] = ky
+        pk[2][rows, cols] = id1[1]  # z = 1: the limbs of one
+
+        sig[0][:n], sig[1][:n] = ec.g2_batch_to_limbs(
+            [s.signature.point for s in sets])
+        sig[2][:n] = id2[1]  # z = 1
+        msg[0][:n], msg[1][:n] = ec.g2_batch_to_limbs(hashes)
+        wbits[:n] = ec.bits_msb_batch(rands[:n], 64)
+        live[:n] = True
 
     return tuple(pk), tuple(sig), tuple(msg), wbits, live
 

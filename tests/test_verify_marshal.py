@@ -8,16 +8,16 @@ import pytest
 from lighthouse_tpu import tracing
 from lighthouse_tpu.crypto.bls import api, curve
 from lighthouse_tpu.crypto.bls.backends.host import _rand_scalars
+from lighthouse_tpu.crypto.bls.fields import Fq
+from lighthouse_tpu.crypto.bls.params import P
 from lighthouse_tpu.ops import ec, tower
 from lighthouse_tpu.ops import verify as verify_mod
 
 
-def _mixed_sets():
-    """Key lists of 1, 3 and 2 keys (the key axis pads to 4), two sets that
-    share a message (a cache hit), and one multi-key aggregate."""
+def _signed_sets(shape):
+    """One signed set per ``(n_keys, message)`` of ``shape``."""
     out = []
-    for i, (n_keys, msg) in enumerate([(1, b"a" * 32), (3, b"b" * 32),
-                                       (2, b"a" * 32), (1, b"c" * 32)]):
+    for i, (n_keys, msg) in enumerate(shape):
         sks = [api.SecretKey(1000 + 17 * i + j) for j in range(n_keys)]
         sig = api.AggregateSignature.infinity()
         for sk in sks:
@@ -25,6 +25,36 @@ def _mixed_sets():
         out.append(api.SignatureSet.multiple_pubkeys(
             sig, [sk.public_key() for sk in sks], msg))
     return out
+
+
+def _mixed_sets():
+    """Key lists of 1, 3 and 2 keys (the key axis pads to 4), two sets that
+    share a message (a cache hit), and one multi-key aggregate."""
+    return _signed_sets([(1, b"a" * 32), (3, b"b" * 32),
+                         (2, b"a" * 32), (1, b"c" * 32)])
+
+
+def _full_bucket_sets():
+    """A set of 8 keys fills the 8-key bucket exactly, beside a 1-key set."""
+    return _signed_sets([(8, b"a" * 32), (1, b"b" * 32)])
+
+
+def _padding_row_sets():
+    """Five sets bucket to eight rows: three rows of pure padding."""
+    return _signed_sets([(2, b"a" * 32), (1, b"b" * 32), (1, b"a" * 32),
+                         (3, b"c" * 32), (1, b"b" * 32)])
+
+
+def _edge_coordinate_sets():
+    """Keys whose coordinates are 0, 1 and p - 1 (not curve points: the
+    marshal packs what the key holds), under valid signatures."""
+    edge = [(0, 1), (1, P - 1), (P - 1, 0), (P - 1, P - 1), (0, 0)]
+    keys = [api.PublicKey((Fq(x), Fq(y)), _skip_checks=True) for x, y in edge]
+    sets = _mixed_sets()[:3]
+    return [api.SignatureSet(sets[0].signature, sets[0].message, keys[:1]),
+            api.SignatureSet(sets[1].signature, sets[1].message, keys[1:4]),
+            api.SignatureSet(sets[2].signature, sets[2].message,
+                             [keys[4], sets[2].signing_keys[0]])]
 
 
 def _reference_pack(sets, rands):
@@ -67,20 +97,28 @@ def _leaves(batch):
     return [*pk, *sig, *msg, wbits, live]
 
 
-def test_phased_marshal_matches_set_by_set_packing():
-    sets = _mixed_sets()
+@pytest.mark.parametrize("make_sets, shape", [
+    (_mixed_sets, (4, 4)),
+    (_full_bucket_sets, (2, 8)),
+    (_padding_row_sets, (8, 4)),
+    (_edge_coordinate_sets, (4, 4)),
+], ids=["mixed", "full_key_bucket", "padding_rows", "edge_coordinates"])
+def test_phased_marshal_matches_set_by_set_packing(make_sets, shape):
+    sets = make_sets()
     rands = _rand_scalars(len(sets), b"marshal-seed")
     with tracing.span("unit_marshal") as root:
         got = verify_mod.build_batch(sets, rands)
     want = _reference_pack(sets, rands)
+    assert got[4].shape == (shape[0],) and got[0][0].shape[:2] == shape
     for g, w in zip(_leaves(got), _leaves(want)):
         assert g.dtype == w.dtype and g.shape == w.shape
         assert g.tobytes() == w.tobytes()
     names = [c.name for c in root.children]
     assert names == ["marshal_subgroup", "marshal_hash_to_g2", "marshal_pack"]
     hashed = root.children[1]
-    assert hashed.fields["n_messages"] == 4
-    assert root.children[2].fields["n_keys"] == 7
+    assert hashed.fields["n_messages"] == len(sets)
+    assert root.children[2].fields["n_keys"] == sum(
+        len(s.signing_keys) for s in sets)
 
 
 def test_hash_misses_are_counted_where_the_cache_is_consulted(monkeypatch):
