@@ -710,6 +710,9 @@ class DevicePipeline:
             raise batch.error  # _execute_loop fails every group's future
         n_sets = len(batch.flat_sets)
         fill = min(1.0, n_sets / self.target_sets)
+        # counted before any future resolves, so a caller woken by its
+        # verdict already sees its batch in the snapshot
+        self.batches_total += 1
         metrics.DEVICE_PIPELINE_BATCHES.inc(op=self.op)
         metrics.DEVICE_PIPELINE_BATCH_FILL_RATIO.observe(fill, op=self.op)
         metrics.DEVICE_PIPELINE_LINGER_SECONDS.observe(batch.linger_s, op=self.op)
@@ -748,7 +751,6 @@ class DevicePipeline:
                     for g in batch.groups:
                         rechecked += 1
                         g.future.set_result(self._recheck_group(g.sets))
-        self.batches_total += 1
         self._recent.append({
             "t_ms": int(time.time() * 1000),
             "n_sets": n_sets,
@@ -1010,6 +1012,7 @@ class HashPipeline:
         for g in groups:
             work_mix[g.future.work] = (
                 work_mix.get(g.future.work, 0) + g.future.n_blocks)
+        self.batches_total += 1  # before any future resolves, as above
         metrics.DEVICE_PIPELINE_BATCHES.inc(op=self.op)
         metrics.DEVICE_PIPELINE_BATCH_FILL_RATIO.observe(fill, op=self.op)
         metrics.DEVICE_PIPELINE_LINGER_SECONDS.observe(linger, op=self.op)
@@ -1044,7 +1047,6 @@ class HashPipeline:
                         g.future.set_result(_host_hash_pairs(g.data))
                     except Exception as host_err:  # noqa: BLE001
                         g.future.set_error(host_err)
-        self.batches_total += 1
         self._recent.append({
             "t_ms": int(time.time() * 1000),
             "n_blocks": n_blocks,

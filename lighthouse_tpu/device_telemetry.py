@@ -264,6 +264,7 @@ def record_batch(
     shape: Tuple[int, ...],
     n_live: int,
     live_keys: Optional[int] = None,
+    key_rows: Optional[int] = None,
     n_groups: Optional[int] = None,
     work_mix: Optional[Dict[str, int]] = None,
     stages: Optional[Dict[str, float]] = None,
@@ -282,7 +283,10 @@ def record_batch(
     (with its ``seq``) so callers can stamp the linkage on their span.
     ``mesh`` > 0 marks a sharded dispatch; ``shard_live`` is the per-shard
     live-row split (the per-shard occupancy view — bucket+mesh padding
-    lands on the last shards, and this is where that shows)."""
+    lands on the last shards, and this is where that shows).  ``key_rows``
+    is the key axis's row count where it differs from ``shape[0]`` (sets
+    spanning rows); the record's ``key_slots`` are its rows times
+    ``shape[1]``."""
     shape = tuple(int(s) for s in shape)
     nb = shape[0]
     entry: Dict[str, Any] = {
@@ -332,10 +336,13 @@ def record_batch(
         for r in ratios:
             metrics.DEVICE_BATCH_OCCUPANCY_RATIO.observe(
                 r, op=op, axis="sets_per_shard")
-    if dispatched and live_keys is not None and len(shape) >= 2 and nb * shape[1] > 0:
-        lanes = nb * shape[1]
+    rows = nb if key_rows is None else int(key_rows)
+    if dispatched and live_keys is not None and len(shape) >= 2 and rows * shape[1] > 0:
+        lanes = rows * shape[1]
         key_ratio = min(1.0, live_keys / lanes)
         entry["live_keys"] = int(live_keys)
+        entry["key_rows"] = rows
+        entry["key_slots"] = lanes
         entry["occupancy_keys"] = round(key_ratio, 4)
         metrics.DEVICE_BATCH_OCCUPANCY_RATIO.observe(key_ratio, op=op, axis="keys")
         metrics.DEVICE_BATCH_WASTED_LANES.inc(

@@ -42,13 +42,15 @@ BATCH_AXES = {
     "lighthouse_tpu/ops/verify.py:_device_verify": {
         "op": "bls_verify",
         "batch_axis": 0,
-        "batched_args": ["pk", "sig", "msg", "wbits", "live"],
+        "batched_args": ["pk", "sig", "msg", "wbits", "live", "rows"],
         "replicated_args": [],
         "reduces_over_batch": False,
         "out_batched": False,
         "notes": "per-set pairing rows; the N+1'th (-g1, W) pair is "
                  "assembled inside the program from a batch-wide MSM — a "
-                 "sharded lowering psums the MSM then replicates the pair",
+                 "sharded lowering psums the MSM then replicates the pair; "
+                 "pk's key rows shard like the sets (rows, when given, "
+                 "gathers across shards)",
     },
     "lighthouse_tpu/ops/sha256_device.py:_sha256_64byte_batch": {
         "op": "sha256_pairs",

@@ -68,8 +68,9 @@ def configure_persistent_cache() -> str:
     return path
 
 
-def _env_buckets() -> Optional[List[Tuple[int, int]]]:
-    """Parse ``LIGHTHOUSE_TPU_AOT_BUCKETS`` ("128x32,4096x32"; case-insensitive
+def _env_buckets() -> Optional[List[Tuple[int, ...]]]:
+    """Parse ``LIGHTHOUSE_TPU_AOT_BUCKETS`` ("128x32,4096x32"; a bucket whose
+    sets span key rows has four dims, "16x2048x64x16"; case-insensitive
     separator, empty parts skipped).  Raises ValueError naming the variable on
     garbage — callers decide whether to fall back."""
     raw = os.environ.get(AOT_BUCKETS_ENV, "").strip()
@@ -80,9 +81,10 @@ def _env_buckets() -> Optional[List[Tuple[int, int]]]:
         part = part.strip()
         if not part:
             continue
-        nb, sep, kb = part.lower().partition("x")
         try:
-            bucket = (int(nb), int(kb))
+            bucket = tuple(int(d) for d in part.lower().split("x"))
+            if len(bucket) not in (2, 4):
+                raise ValueError(part)
         except ValueError:
             raise ValueError(
                 f"{AOT_BUCKETS_ENV}={raw!r}: expected e.g. \"128x32,4096x32\""
@@ -105,19 +107,24 @@ def _cache_file_count() -> Optional[int]:
         return None
 
 
-def _bucket_shape_structs(nb: int, kb: int):
+def _bucket_shape_structs(nb: int, kb: int, nr: Optional[int] = None,
+                          rb: Optional[int] = None):
     """Abstract argument shapes of ``_device_verify`` for one bucket — the
-    exact dtypes/shapes ``build_batch`` marshals, with no host crypto."""
+    exact dtypes/shapes ``build_batch`` marshals, with no host crypto.
+    ``(nb, kb)``: one key row a set; ``(nb, kb, nr, rb)``: ``nr`` key rows,
+    up to ``rb`` of them a set (``verify.bucket_shape``)."""
     import jax
     import numpy as np
 
     i32 = np.int32
-    pk = tuple(jax.ShapeDtypeStruct((nb, kb, 25), i32) for _ in range(3))
+    pk = tuple(jax.ShapeDtypeStruct((nb if nr is None else nr, kb, 25), i32)
+               for _ in range(3))
     sig = tuple(jax.ShapeDtypeStruct((nb, 2, 25), i32) for _ in range(3))
     msg = tuple(jax.ShapeDtypeStruct((nb, 2, 25), i32) for _ in range(2))
     wbits = jax.ShapeDtypeStruct((nb, 64), i32)
     live = jax.ShapeDtypeStruct((nb,), np.bool_)
-    return pk, sig, msg, wbits, live
+    rows = None if nr is None else jax.ShapeDtypeStruct((nb, rb), i32)
+    return pk, sig, msg, wbits, live, rows
 
 
 def kzg_shape_structs(nb: int):
@@ -291,7 +298,7 @@ def aot_warmup_op(op: str, nb: int) -> List[dict]:
 
 
 def warmup_standard_buckets(
-    buckets: Optional[Sequence[Tuple[int, int]]] = None,
+    buckets: Optional[Sequence[Tuple[int, ...]]] = None,
     *,
     hit_threshold_s: float = WARMUP_HIT_THRESHOLD_S,
 ) -> List[dict]:
@@ -317,12 +324,12 @@ def warmup_standard_buckets(
             buckets = None
         buckets = buckets or list(DEFAULT_WARMUP_BUCKETS)
     results: List[dict] = []
-    for nb, kb in buckets:
-        nb, kb = int(nb), int(kb)
+    for shape in buckets:
+        shape = tuple(int(d) for d in shape)
         results.append(_aot_compile(
-            "bls_verify", (nb, kb),
-            lambda nb=nb, kb=kb: _device_verify.lower(
-                *_bucket_shape_structs(nb, kb)).compile(),
+            "bls_verify", shape,
+            lambda shape=shape: _device_verify.lower(
+                *_bucket_shape_structs(*shape)).compile(),
             hit_threshold_s=hit_threshold_s,
         ))
     return results

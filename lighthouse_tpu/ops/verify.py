@@ -14,7 +14,11 @@ signature subgroup/infinity checks, hash-to-curve (SHA-256), shape bucketing.
 
 Shape discipline: programs are compiled per (n_sets_bucket, max_keys_bucket);
 batches are padded with identity points + zero weights, which flow through the
-complete formulas and masked Miller loop as exact neutral elements.
+complete formulas and masked Miller loop as exact neutral elements.  A set
+wider than the top key bucket (an Electra on-chain aggregate spans up to 64
+committees) is laid over several key rows of the top bucket's width, and the
+program sums each set's row sums; the bucket is then (n_sets_bucket,
+row_width, key_rows_bucket, rows_per_set_bucket).
 
 Edge cases (parity with the host backend, tests/test_backend_jax.py):
  - empty batch, missing/out-of-subgroup signature, empty pubkey list -> False on host
@@ -26,7 +30,9 @@ Edge cases (parity with the host backend, tests/test_backend_jax.py):
 
 from __future__ import annotations
 
+import collections
 import functools
+import threading
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -89,24 +95,40 @@ autotune.register_vocabulary(
 )
 
 
+def _set_sums(row_sums, rows):
+    """Each set's aggregate key: the sum of its rows' sums.  ``rows`` is
+    (N, RB) int32, the key rows of each set, -1 where a set has no more;
+    those slots read the identity, and a complete-formula tree sums each
+    set's RB slots."""
+    taken = tuple(c[jnp.maximum(rows, 0)] for c in row_sums)  # (N, RB, 25)
+    pts = ec.point_select(rows >= 0, taken, ec.identity(ec.G1_OPS))
+    return ec.tree_sum(ec.G1_OPS, pts, axis=1)
+
+
 @jax.jit
-def _device_verify(pk, sig, msg, wbits, live):
+def _device_verify(pk, sig, msg, wbits, live, rows=None):
     """The fused device program.
 
-    pk:   G1 projective, coords (N, K, 25) — per-set key lists, identity-padded
+    pk:   G1 projective, coords (R, K, 25) — key rows, identity-padded; row i
+          is set i's keys (R == N) unless ``rows`` is given
     sig:  G2 projective, coords (N, 2, 25)
     msg:  G2 affine hash points, coords (N, 2, 25)
     wbits:(N, 64) int32, MSB-first random weights (zero rows for padding)
     live: (N,) bool
+    rows: None, or (N, RB) int32: the key rows of each set, -1 past its last
     Returns (fe, w_z): final-exponentiation output (12-coeff limbs) and the
     Z coordinate of W = sum_i [r_i] sig_i for the host-side infinity check.
 
     The stages carry named scopes (HLO metadata only), which
     ``device_telemetry.program_stages`` maps the compiled instructions to:
-    s1-s3 here, ``miller_loop`` and ``final_exp`` (s4, s5) in the pairing.
+    s1-s3 here (``s1_set_sum`` only where ``rows`` is given), ``miller_loop``
+    and ``final_exp`` (s4, s5) in the pairing.
     """
     with jax.named_scope("s1_key_sum"):
-        agg = ec.tree_sum(ec.G1_OPS, pk, axis=1)              # (N,) G1 proj
+        agg = ec.tree_sum(ec.G1_OPS, pk, axis=1)              # (R,) G1 proj
+    if rows is not None:
+        with jax.named_scope("s1_set_sum"):
+            agg = _set_sums(agg, rows)                        # (N,) G1 proj
     # [r_i] aggpk_i stays per-set (each feeds its own pairing); windowed
     # ladder instead of double-and-add (VERDICT r3 item 2).
     with jax.named_scope("s2_g1_ladder"):
@@ -133,25 +155,29 @@ def _device_verify(pk, sig, msg, wbits, live):
 
 
 #: The named scopes of ``_device_verify``'s stages s1-s5, in program order.
-STAGES = ("s1_key_sum", "s2_g1_ladder", "s3_g2_msm", "miller_loop", "final_exp")
+STAGES = ("s1_key_sum", "s1_set_sum", "s2_g1_ladder", "s3_g2_msm",
+          "miller_loop", "final_exp")
 
 
-def lower_for_stages(shape: Tuple[int, int]):
-    """``_device_verify`` lowered at the bucket ``(nb, kb)`` with the
-    dispatched arguments' types, so jit's caches hand back the executable
-    the dispatches ran (``device_telemetry.program_stages``)."""
-    nb, kb = shape
+def bucket_shape(batch) -> Tuple[int, ...]:
+    """The bucket a host or placed batch was built at: ``(nb, kb)``, or
+    ``(nb, kb, nr, rb)`` where sets span key rows (``nr`` key rows of width
+    ``kb``, up to ``rb`` of them a set)."""
+    pk, live, rows = batch[0], batch[4], batch[5]
+    nb, kb = int(live.shape[0]), int(pk[0].shape[1])
+    if rows is None:
+        return nb, kb
+    return nb, kb, int(pk[0].shape[0]), int(rows.shape[1])
 
-    def limbs(*dims):
-        return jax.ShapeDtypeStruct(dims + (25,), jnp.int32)
 
-    return _device_verify.lower(
-        tuple(limbs(nb, kb) for _ in range(3)),
-        tuple(limbs(nb, 2) for _ in range(3)),
-        tuple(limbs(nb, 2) for _ in range(2)),
-        jax.ShapeDtypeStruct((nb, 64), jnp.int32),
-        jax.ShapeDtypeStruct((nb,), jnp.bool_),
-    )
+def lower_for_stages(shape: Tuple[int, ...]):
+    """``_device_verify`` lowered at the bucket ``shape`` (see
+    :func:`bucket_shape`) with the dispatched arguments' types, so jit's
+    caches hand back the executable the dispatches ran
+    (``device_telemetry.program_stages``)."""
+    from .compile_cache import _bucket_shape_structs
+
+    return _device_verify.lower(*_bucket_shape_structs(*shape))
 
 
 # --------------------------------------------------------------- host driver
@@ -195,21 +221,26 @@ def _sharded_entry():
 def _pad_host_rows(host_batch: tuple, nbp: int) -> tuple:
     """Grow the batch axis to ``nbp`` rows with the exact neutral padding
     ``build_batch`` uses (identity points, generator hash slots, zero
-    weights, dead ``live`` rows) — the mesh-divisibility pad."""
+    weights, dead ``live`` rows, no key rows) — the mesh-divisibility pad.
+    Key rows of a batch whose sets span rows grow to their own multiple of
+    the mesh size."""
     from .. import device_mesh
 
-    pk, sig, msg, wbits, live = host_batch
+    pk, sig, msg, wbits, live, rows = host_batch
     nb = live.shape[0]
-    if nbp == nb:
+    nrp = nbp if rows is None else device_mesh.pad_rows(int(pk[0].shape[0]))
+    if nbp == nb and nrp == pk[0].shape[0]:
         return host_batch
     id1, id2 = ec.g1_to_limbs(None), ec.g2_to_limbs(None)
     grow = device_mesh.grow_rows
-    pk = tuple(grow(pk[c], nbp, id1[c]) for c in range(3))
+    pk = tuple(grow(pk[c], nrp, id1[c]) for c in range(3))
     sig = tuple(grow(sig[c], nbp, id2[c]) for c in range(3))
     msg = tuple(grow(msg[c], nbp, _G2_GEN_AFF[c]) for c in range(2))
     wbits = grow(wbits, nbp, 0)
     live = grow(live, nbp, False)
-    return pk, sig, msg, wbits, live
+    if rows is not None:
+        rows = grow(rows, nbp, -1)
+    return pk, sig, msg, wbits, live, rows
 
 
 def place_batch(host_batch: tuple) -> Tuple[tuple, int, int]:
@@ -235,22 +266,68 @@ def place_batch(host_batch: tuple) -> Tuple[tuple, int, int]:
         nbp = device_mesh.pad_rows(int(host_batch[4].shape[0]))
         placed = entry.place(*_pad_host_rows(host_batch, nbp))
         return placed, device_mesh.size(), gen
-    pk, sig, msg, wbits, live = host_batch
+    pk, sig, msg, wbits, live, rows = host_batch
     placed = (
         tuple(jnp.asarray(a) for a in pk),
         tuple(jnp.asarray(a) for a in sig),
         tuple(jnp.asarray(a) for a in msg),
         jnp.asarray(wbits),
         jnp.asarray(live),
+        None if rows is None else jnp.asarray(rows),
     )
     return placed, 0, gen
 
 
+#: Host key arrays kept for the next batch of the same (rows, width): a
+#: block's three (rows, width, 25) limb arrays are tens of MB, and fresh
+#: pages fault in as the pack writes them, where recycled ones do not.  One
+#: set per shape, at most ``_KEY_ARRAYS_MAX_BYTES`` in all, the least
+#: recently returned dropped first.
+_KEY_ARRAYS: "collections.OrderedDict[tuple, tuple]" = collections.OrderedDict()
+_KEY_ARRAYS_MAX_BYTES = 256 << 20
+_key_arrays_lock = threading.Lock()
+
+
+def _key_arrays(nr: int, kb: int) -> list:
+    """Zeroed (nr, kb, 25) int32 arrays for x, y and z: recycled where a
+    finished batch returned a set of this shape, else new."""
+    with _key_arrays_lock:
+        pk = _KEY_ARRAYS.pop((nr, kb), None)
+    if pk is None:
+        return [np.zeros((nr, kb, 25), np.int32) for _ in range(3)]
+    for a in pk:
+        a.fill(0)
+    return list(pk)
+
+
+def _return_key_arrays(pk) -> None:
+    """Keep a finished batch's key arrays for the next batch of their shape."""
+    nbytes = sum(a.nbytes for a in pk)
+    if nbytes > _KEY_ARRAYS_MAX_BYTES:
+        return
+    key = pk[0].shape[:2]
+    with _key_arrays_lock:
+        _KEY_ARRAYS[key] = tuple(pk)
+        _KEY_ARRAYS.move_to_end(key)
+        total = sum(a.nbytes for v in _KEY_ARRAYS.values() for a in v)
+        while total > _KEY_ARRAYS_MAX_BYTES:
+            _, old = _KEY_ARRAYS.popitem(last=False)
+            total -= sum(a.nbytes for a in old)
+
+
 def build_batch(sets, rands) -> Optional[tuple]:
     """Validate + marshal signature sets into padded HOST arrays (numpy,
-    bucket-shaped).  Placement — single-device or mesh-sharded — is
+    bucket-shaped): ``(pk, sig, msg, wbits, live, rows)``, the arguments of
+    ``_device_verify``.  Placement — single-device or mesh-sharded — is
     :func:`place_batch`; jit accepts the numpy arrays directly, so callers
     that dispatch these straight into ``_device_verify`` still work.
+
+    Set i's keys fill key row i, and ``rows`` is None, while every set fits
+    the top key bucket.  Past it, every row is the top bucket's width
+    ``K_BUCKETS[-1]``, set i takes ``ceil(w_i / K_BUCKETS[-1])`` consecutive
+    rows and ``rows[i]`` lists them (-1 past its last): no row is padded to
+    the widest set's width.  The row count and the rows a set takes bucket
+    against ``N_BUCKETS``.
 
     Returns None if host-side validation already decides False.
 
@@ -266,7 +343,12 @@ def build_batch(sets, rands) -> Optional[tuple]:
     # plus any controller-adopted overlay buckets); the key axis stays
     # static — padding waste there is bounded by the committee shape.
     nb = _bucket(n, autotune.bucket_vocabulary("bls_verify", N_BUCKETS))
-    kb = _bucket(max(len(s.signing_keys) for s in sets), K_BUCKETS)
+    counts = np.fromiter((len(s.signing_keys) for s in sets), np.intp, n)
+    spans = int(counts.max()) > K_BUCKETS[-1]
+    kb = K_BUCKETS[-1] if spans else _bucket(int(counts.max()), K_BUCKETS)
+    set_rows = np.maximum(1, -(-counts // kb))  # key rows each set takes
+    n_rows = int(set_rows.sum())
+    nr = _bucket(n_rows, N_BUCKETS) if spans else nb
 
     with tracing.span("marshal_subgroup", n_sets=n):
         for s in sets:
@@ -281,9 +363,9 @@ def build_batch(sets, rands) -> Optional[tuple]:
         hashes = [_hash_to_g2_cached(s.message, misses) for s in sets]
         sp_hash.fields["n_hash_misses"] = len(misses)
 
-    n_keys = sum(len(s.signing_keys) for s in sets)
-    with tracing.span("marshal_pack", n_keys=n_keys):
-        pk = [np.zeros((nb, kb, 25), np.int32) for _ in range(3)]
+    n_keys = int(counts.sum())
+    with tracing.span("marshal_pack", n_keys=n_keys, n_rows=n_rows, k_row=kb):
+        pk = _key_arrays(nr, kb)
         sig = [np.zeros((nb, 2, 25), np.int32) for _ in range(3)]
         msg = [np.zeros((nb, 2, 25), np.int32) for _ in range(2)]
         wbits = np.zeros((nb, 64), np.int32)
@@ -298,15 +380,24 @@ def build_batch(sets, rands) -> Optional[tuple]:
         for c in range(2):
             msg[c][:] = _G2_GEN_AFF[c]
 
-        # Key j of set i goes to row i, column j: one scatter for all keys.
-        counts = np.fromiter((len(s.signing_keys) for s in sets), np.intp, n)
-        rows = np.repeat(np.arange(n), counts)
-        cols = np.arange(n_keys) - np.repeat(np.cumsum(counts) - counts, counts)
+        # Key j of set i goes to row first[i] + j // kb, column j % kb: one
+        # scatter for all keys.
+        first = np.cumsum(set_rows) - set_rows
+        j = np.arange(n_keys) - np.repeat(np.cumsum(counts) - counts, counts)
+        krow = np.repeat(first, counts) + j // kb
+        kcol = j % kb
         kx, ky = ec.g1_batch_to_limbs(
             [key.point for s in sets for key in s.signing_keys])
-        pk[0][rows, cols] = kx
-        pk[1][rows, cols] = ky
-        pk[2][rows, cols] = id1[1]  # z = 1: the limbs of one
+        pk[0][krow, kcol] = kx
+        pk[1][krow, kcol] = ky
+        pk[2][krow, kcol] = id1[1]  # z = 1: the limbs of one
+
+        rows = None
+        if spans:
+            rb = _bucket(int(set_rows.max()), N_BUCKETS)
+            r = np.arange(rb)
+            rows = np.full((nb, rb), -1, np.int32)
+            rows[:n] = np.where(r < set_rows[:, None], first[:, None] + r, -1)
 
         sig[0][:n], sig[1][:n] = ec.g2_batch_to_limbs(
             [s.signature.point for s in sets])
@@ -315,7 +406,7 @@ def build_batch(sets, rands) -> Optional[tuple]:
         wbits[:n] = ec.bits_msb_batch(rands[:n], 64)
         live[:n] = True
 
-    return tuple(pk), tuple(sig), tuple(msg), wbits, live
+    return tuple(pk), tuple(sig), tuple(msg), wbits, live, rows
 
 
 def _device_batch_verdict(built: "BuiltBatch", stages: dict,
@@ -332,15 +423,16 @@ def _device_batch_verdict(built: "BuiltBatch", stages: dict,
     from .. import device_supervisor, device_telemetry, fault_injection, metrics, tracing
 
     built.ensure_placed()  # a reshard since placement re-pads + re-uploads
-    batch, nb, kb, mesh = built.batch, built.nb, built.kb, built.mesh
+    batch, bucket, mesh = built.batch, built.bucket, built.mesh
+    nb, kb = built.nb, built.kb
     if fault_injection.ACTIVE:
-        if not device_telemetry.COMPILE_CACHE.seen("bls_verify", (nb, kb),
+        if not device_telemetry.COMPILE_CACHE.seen("bls_verify", bucket,
                                                    mesh=mesh):
             fault_injection.check("device.compile", op="bls_verify")
         fault_injection.check("device.dispatch", op="bls_verify")
     with tracing.span(
         "device_batch_dispatch", hist=metrics.DEVICE_DISPATCH_SECONDS,
-        n_bucket=nb, k_bucket=kb, mesh=mesh,
+        n_bucket=nb, k_bucket=kb, r_bucket=built.key_rows, mesh=mesh,
     ) as sp_dispatch:
         if mesh:
             fe, w_z = _sharded_entry()(*batch)
@@ -349,7 +441,7 @@ def _device_batch_verdict(built: "BuiltBatch", stages: dict,
     # First dispatch of a shape pays trace+compile inside the call itself:
     # the dispatch duration IS the compile-time observation for that shape.
     compiled = device_telemetry.note_dispatch(
-        "bls_verify", (nb, kb), sp_dispatch.duration, mesh=mesh
+        "bls_verify", bucket, sp_dispatch.duration, mesh=mesh
     )
     if compiled:
         sp_dispatch.fields["compiled"] = True
@@ -396,7 +488,6 @@ def _device_verify_subset(subset, seed: Optional[bytes]) -> bool:
     if host_batch is None:
         return False
     batch, mesh, _ = place_batch(host_batch)
-    nb, kb = int(batch[0][0].shape[0]), int(batch[0][0].shape[1])
     if fault_injection.ACTIVE:
         fault_injection.check("device.dispatch", op="bls_verify")
     import time as _time
@@ -407,7 +498,7 @@ def _device_verify_subset(subset, seed: Optional[bytes]) -> bool:
     else:
         fe, w_z = _device_verify(*batch)
     device_telemetry.note_dispatch(
-        "bls_verify", (nb, kb), _time.perf_counter() - t0, mesh=mesh
+        "bls_verify", bucket_shape(batch), _time.perf_counter() - t0, mesh=mesh
     )
     jax.block_until_ready((fe, w_z))
     if tower.fq2_from_limbs(np.asarray(w_z)).is_zero():
@@ -431,7 +522,7 @@ class BuiltBatch:
     mesh size — and :meth:`ensure_placed` re-pads + re-uploads from the
     host copy under the surviving topology."""
 
-    __slots__ = ("sets", "seed", "host", "batch", "nb", "kb", "mesh",
+    __slots__ = ("sets", "seed", "host", "batch", "bucket", "mesh",
                  "generation", "live_keys", "setup_s")
 
     def __init__(self, sets, seed, host_batch, setup_s: float):
@@ -442,8 +533,7 @@ class BuiltBatch:
         self.host = host_batch
         with tracing.span("marshal_upload"):
             self.batch, self.mesh, self.generation = place_batch(host_batch)
-        self.nb = int(self.batch[0][0].shape[0])
-        self.kb = int(self.batch[0][0].shape[1])
+        self.bucket = bucket_shape(self.batch)
         self.live_keys = sum(len(s.signing_keys) for s in sets)
         self.setup_s = setup_s
 
@@ -454,8 +544,28 @@ class BuiltBatch:
 
         if device_mesh.generation() != self.generation:
             self.batch, self.mesh, self.generation = place_batch(self.host)
-            self.nb = int(self.batch[0][0].shape[0])
-            self.kb = int(self.batch[0][0].shape[1])
+            self.bucket = bucket_shape(self.batch)
+
+    def release(self) -> None:
+        """Return the host key arrays for the next batch of their shape,
+        once the device is done with this one; the batch cannot be placed
+        again."""
+        host, self.host, self.batch = self.host, None, None
+        if host is not None:
+            _return_key_arrays(host[0])
+
+    @property
+    def nb(self) -> int:
+        return self.bucket[0]
+
+    @property
+    def kb(self) -> int:
+        return self.bucket[1]
+
+    @property
+    def key_rows(self) -> int:
+        """Rows of the key axis: ``nb``, or ``nr`` where sets span rows."""
+        return self.bucket[2] if len(self.bucket) > 2 else self.bucket[0]
 
 
 def build_device_batch(sets, seed: Optional[bytes] = None) -> Optional[BuiltBatch]:
@@ -513,7 +623,9 @@ def execute_built_batch(built: BuiltBatch, *, n_groups: int = 1,
         stages_local: dict = {}
         state_local = {"compiled": False}
         try:
-            return _device_batch_verdict(built, stages_local, state_local)
+            ok = _device_batch_verdict(built, stages_local, state_local)
+            holder["done"] = True  # the device has read every input
+            return ok
         finally:
             holder["stages"] = stages_local
             holder["state"] = state_local
@@ -542,6 +654,8 @@ def execute_built_batch(built: BuiltBatch, *, n_groups: int = 1,
     if reason != "dispatch_timeout":
         stages.update(holder.get("stages") or {})
         compiled = (holder.get("state") or {}).get("compiled", False)
+    if holder.get("done"):
+        built.release()
     # built.nb/mesh read AFTER the run: a mid-run reshard re-placed the
     # batch, and the record must describe the topology that executed.
     mesh = built.mesh
@@ -551,9 +665,10 @@ def execute_built_batch(built: BuiltBatch, *, n_groups: int = 1,
     )
     rec = device_telemetry.record_batch(
         op="bls_verify",
-        shape=(built.nb, built.kb),
+        shape=built.bucket,
         n_live=len(sets),
         live_keys=built.live_keys,
+        key_rows=built.key_rows,
         n_groups=n_groups,
         work_mix=work_mix,
         stages=stages,

@@ -358,7 +358,7 @@ def _targets() -> List[Target]:
             msg = tuple(S((nb, 2, 25), i32) for _ in range(2))
             return (
                 unwrap(verify._device_verify),
-                (pk, sig, msg, S((nb, 64), i32), S((nb,), jnp.bool_)),
+                (pk, sig, msg, S((nb, 64), i32), S((nb,), jnp.bool_), None),
             )
         return build
 

@@ -204,7 +204,7 @@ def main() -> None:
                  "fq_backend": fq_mod.active_fq_backend()}
 
     t0 = time.perf_counter()
-    pk, sig, msg, wbits, live = _build_example(n_sets=n, n_keys=k, seed=3)
+    pk, sig, msg, wbits, live, _ = _build_example(n_sets=n, n_keys=k, seed=3)
     res["build_batch_secs"] = round(time.perf_counter() - t0, 2)
 
     stages = []

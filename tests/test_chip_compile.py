@@ -76,6 +76,17 @@ def test_verify_128x32_int8(one_chip, int8_lowering):
     assert lowered.compile().memory_analysis().temp_size_in_bytes > 0
 
 
+def test_verify_electra_block_rows_int8(one_chip, int8_lowering):
+    """An Electra block's bucket: 11 sets in 16, the 26,560-key aggregate
+    over 13 key rows of 2,048 (16 a set), 37 rows in the 64-row bucket."""
+    from lighthouse_tpu.ops.verify import _device_verify
+
+    lowered = _device_verify.lower(
+        *_on(one_chip, compile_cache._bucket_shape_structs(16, 2048, 64, 16)))
+    assert "s1_set_sum" in lowered.as_text(debug_info=True)
+    assert lowered.compile().memory_analysis().temp_size_in_bytes > 0
+
+
 def test_epoch_boundary_2_20(one_chip):
     from lighthouse_tpu.ops.shuffle_device import _boundary_kernel
 

@@ -216,7 +216,7 @@ def test_sharded_entry_specs_derive_from_registry():
     bls = device_mesh.ShardedEntry(
         verify.ENTRY_KEY, verify._device_verify.__wrapped__)
     specs = bls.in_shardings(mesh)
-    assert len(specs) == 5
+    assert len(specs) == 6                            # ... live, rows
     assert all(s.spec == P("dp") for s in specs)      # all batched
     assert bls.out_sharding(mesh).spec == P()         # batch-reduced output
 
@@ -241,3 +241,30 @@ def test_shard_live_counts_pack_padding_on_last_shards():
     assert sum(entry.shard_live_counts(100, 128)) == 100
     device_mesh.force_trip(7)
     assert entry.shard_live_counts(12, 21) == [3, 3, 3, 3, 0, 0, 0]
+
+
+def test_mesh_pad_of_key_rows_and_their_index(monkeypatch):
+    """Where sets span key rows, the mesh pad grows the set axis (with
+    ``rows`` at -1: no key row) and the key-row axis, each to its own
+    multiple of the mesh size, with the neutral fills ``build_batch`` uses."""
+    from lighthouse_tpu.crypto.bls import api
+    from lighthouse_tpu.crypto.bls.backends.host import _rand_scalars
+    from lighthouse_tpu.ops import ec, verify
+
+    monkeypatch.setattr(verify, "K_BUCKETS", (1, 2, 4))
+    sks = [api.SecretKey(7000 + i) for i in range(9)]
+    agg = api.SecretKey(sum(sk.scalar for sk in sks))
+    sets = [api.SignatureSet.multiple_pubkeys(
+        agg.sign(b"pad"), [sk.public_key() for sk in sks], b"pad")]
+    host_batch = verify.build_batch(sets, _rand_scalars(1, b"pad"))
+    assert verify.bucket_shape(host_batch) == (1, 4, 4, 4)
+    device_mesh.configure("auto")
+    padded = verify._pad_host_rows(host_batch, device_mesh.pad_rows(1))
+    assert verify.bucket_shape(padded) == (8, 4, 8, 4)
+    pk, sig, msg, wbits, live, rows = padded
+    assert rows[0].tolist() == [0, 1, 2, -1] and (rows[1:] == -1).all()
+    assert live.tolist() == [True] + [False] * 7 and not wbits[1:].any()
+    id1 = ec.g1_to_limbs(None)
+    for c in range(3):
+        assert (pk[c][:4] == host_batch[0][c]).all()
+        assert (pk[c][4:] == id1[c]).all()           # identity key rows

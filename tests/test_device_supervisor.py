@@ -255,9 +255,10 @@ class TestSupervisedBlsVerify:
                    sleep_s=1.5, first_n=1)
         to_before = metrics.DEVICE_DISPATCH_TIMEOUTS.get(op="bls_verify")
         fb_before = _fallbacks("dispatch_timeout")
-        t0 = time.perf_counter()
         s = make_set(b"hang-fault")
         assert verify_signature_sets_device([s], seed=b"t") is True
+        stranded = [t for t in threading.enumerate()
+                    if t.name == "device-dispatch-bls_verify"]
         # the caller resolved through the host without waiting out the hang
         assert metrics.DEVICE_DISPATCH_TIMEOUTS.get(
             op="bls_verify") == to_before + 1
@@ -267,9 +268,12 @@ class TestSupervisedBlsVerify:
         # a fresh worker serves the next batch on the device
         assert verify_signature_sets_device([s], seed=b"t") is True
         assert ds.SUPERVISOR.breaker("bls_verify").state == "closed"
-        # let the abandoned worker finish its hang: woken inside the next
-        # test, it would fire that test's fault plans
-        time.sleep(max(0.0, t0 + 1.6 - time.perf_counter()))
+        # let the abandoned worker finish its hang and the batch it then
+        # dispatches: still running inside the next test, its verdict would
+        # fire that test's fault plans
+        for t in stranded:
+            t.join(timeout=60)
+            assert not t.is_alive()
 
     def test_split_half_disclaimer_is_not_a_breaker_failure(self):
         """A HostFallback raised by a split half (W at infinity) routes to

@@ -220,3 +220,57 @@ def test_exit_churn_is_balance_weighted():
     assert whale_exit > h.compute_activation_exit_epoch(
         h.get_current_epoch(state, spec), spec
     ), "a whale exit must push past the base activation-exit epoch"
+
+
+def test_electra_aggregate_over_committees_on_the_jax_backend(monkeypatch):
+    """An EIP-7549 on-chain aggregate over every committee of a slot, built
+    through ``consensus/signature_sets`` with real keys, verified by the jax
+    backend with its key axis spanning rows (top key bucket shrunk to 4, so
+    the slot's 2 committees of 4 keys take two rows) — and refused once a
+    committee's signer is missing from the aggregate signature."""
+    from lighthouse_tpu import device_telemetry
+    from lighthouse_tpu.consensus import signature_sets as ss
+    from lighthouse_tpu.crypto.bls import api as bls
+    from lighthouse_tpu.crypto.bls.backends import host
+    from lighthouse_tpu.crypto.bls.params import R
+    from lighthouse_tpu.ops import verify as v
+    from lighthouse_tpu.types.spec import DOMAIN_BEACON_ATTESTER
+
+    spec = minimal_spec(
+        altair_fork_epoch=0, bellatrix_fork_epoch=0, capella_fork_epoch=0,
+        deneb_fork_epoch=0, electra_fork_epoch=0,
+    )
+    harness = BeaconChainHarness(validator_count=64, spec=spec)
+    state = harness.chain.head_state
+    slot = int(state.slot)
+    epoch = h.compute_epoch_at_slot(slot, spec)
+    committees = h.get_committee_count_per_slot(state, epoch, spec)
+    assert committees == 2
+    data = harness.chain.produce_attestation_data(slot, 0)
+    bits, members = [], []
+    for ci in range(committees):
+        committee = h.get_beacon_committee(state, slot, ci, spec)
+        bits += [True] * len(committee)
+        members += [int(i) for i in committee]
+    root = h.compute_signing_root(
+        data.hash_tree_root(),
+        h.get_domain(state, DOMAIN_BEACON_ATTESTER, epoch, spec))
+
+    def aggregate(signers):
+        sk = bls.SecretKey(sum(harness.keys[i].scalar for i in signers) % R)
+        att = harness.types.AttestationElectra(
+            aggregation_bits=bits, data=data, signature=sk.sign(root).to_bytes(),
+            committee_bits=[ci < committees for ci in
+                            range(spec.preset.max_committees_per_slot)])
+        indexed = h.get_indexed_attestation(state, att, harness.types, spec)
+        return ss.indexed_attestation_signature_set(state, indexed, spec)
+
+    monkeypatch.setattr(v, "K_BUCKETS", (1, 2, 4))
+    set_backend("jax")
+    for signers, want in ((members, True), (members[1:], False)):
+        s = aggregate(signers)
+        assert len(s.signing_keys) == 8
+        assert host.verify_signature_sets([s], seed=b"el") is want
+        assert bls.verify_signature_sets([s]) is want
+        rec = device_telemetry.FLIGHT_RECORDER.recent(op="bls_verify")[0]
+        assert rec["shape"] == "1x4x2x2" and not rec["host_fallback"]

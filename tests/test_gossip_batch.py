@@ -293,8 +293,14 @@ def test_lazy_peers_pull_via_iwant():
     bounded by gossipsub v1.1's D_high (inbound GRAFTs legitimately grow
     the mesh past D until the heartbeat prunes at D_high) — strictly fewer
     than its 13 connected peers, so dissemination is NOT a flood; every
-    node still converges on the block (mesh push + IHAVE -> IWANT pull)."""
-    from lighthouse_tpu.network.service import MESH_DEGREE_HIGH
+    node still converges on the block (mesh push + IHAVE -> IWANT pull).
+    The block is published once every mesh holds D_low members, so no
+    heartbeat grafts to fill one, and the publisher has run a heartbeat a
+    full interval later, which prunes the GRAFTs it took past D_high."""
+    import time
+
+    from lighthouse_tpu.network.service import (
+        HEARTBEAT_SECS, MESH_DEGREE_HIGH, MESH_DEGREE_LOW)
 
     n_nodes = 14
     set_backend("fake")
@@ -314,6 +320,21 @@ def test_lazy_peers_pull_via_iwant():
                     hub.connect(f"m{i:02d}", f"m{j:02d}")
             for hs in harnesses:
                 hs.advance_slot()
+            topic = str(topics_mod.GossipTopic(
+                nodes[0].router.fork_digest, topics_mod.BEACON_BLOCK))
+            deadline = time.monotonic() + 15
+
+            def formed():
+                return all(len(n.service.mesh.get(topic, ())) >= MESH_DEGREE_LOW
+                           for n in nodes)
+
+            while not formed() and time.monotonic() < deadline:
+                time.sleep(0.1)
+            assert formed(), "a node never formed its mesh"
+            t_formed = time.monotonic()
+            while (nodes[0].service._last_heartbeat < t_formed + HEARTBEAT_SECS
+                   and time.monotonic() < deadline):
+                time.sleep(0.1)
             signed = harnesses[0].produce_signed_block(slot=1)
             root = signed.message.hash_tree_root()
             harnesses[0].chain.process_block(signed)
@@ -321,7 +342,6 @@ def test_lazy_peers_pull_via_iwant():
             assert sent <= MESH_DEGREE_HIGH < n_nodes - 1, (
                 f"publisher eagerly pushed to {sent} peers (flood, not mesh)"
             )
-            import time
 
             deadline = time.monotonic() + 15
             while time.monotonic() < deadline:

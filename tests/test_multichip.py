@@ -38,7 +38,7 @@ def _sharded_fn():
 
 
 def _shard_args(batch, dp):
-    pk, sig, msg, wbits, live = batch
+    pk, sig, msg, wbits, live, rows = batch
     shard = lambda x: jax.device_put(x, dp)
     return (
         tuple(shard(c) for c in pk),
@@ -46,6 +46,7 @@ def _shard_args(batch, dp):
         tuple(shard(c) for c in msg),
         shard(wbits),
         shard(live),
+        None if rows is None else shard(rows),
     )
 
 
@@ -66,9 +67,9 @@ def test_sharded_verify_rejects_bad_signature():
     from lighthouse_tpu.ops.pairing import fe_is_one
 
     fn, dp = _sharded_fn()
-    pk, sig, msg, wbits, live = _build_example(n_sets=N_SETS, n_keys=2)
+    pk, sig, msg, wbits, live, rows = _build_example(n_sets=N_SETS, n_keys=2)
     # Corrupt the hash points: swap x and y limb blocks.
-    batch = (pk, sig, (msg[1], msg[0]), wbits, live)
+    batch = (pk, sig, (msg[1], msg[0]), wbits, live, rows)
     fe, _ = fn(*_shard_args(batch, dp))
     assert not fe_is_one(fe)
 
@@ -140,8 +141,8 @@ def test_sharded_uneven_live_batch_100_over_8():
     assert np.array_equal(np.asarray(fe_mesh), np.asarray(fe_one))
 
     # and a corrupted LIVE row still fails while dead rows stay inert
-    pk, sig, msg, wbits, live_arr = batch
-    bad = (pk, sig, (msg[1], msg[0]), wbits, live_arr)
+    pk, sig, msg, wbits, live_arr, rows = batch
+    bad = (pk, sig, (msg[1], msg[0]), wbits, live_arr, rows)
     fe_bad, _ = fn(*_shard_args(bad, dp))
     assert not fe_is_one(fe_bad)
 
@@ -276,6 +277,31 @@ def test_supervised_sharded_bls_rejects_bad_set():
     assert verify_signature_sets_device(sets, seed=b"mesh-par") is False
     with _mesh():
         assert verify_signature_sets_device(sets, seed=b"mesh-par") is False
+
+
+def test_supervised_sharded_wide_sets_match_single_device(monkeypatch):
+    """Sets wider than the top key bucket (shrunk to 4 keys) on the mesh:
+    the key rows and their index shard with the batch, the set sums gather
+    across shards, and the verdicts match the single-device path and the
+    host golden model for a sound and a tampered batch."""
+    from lighthouse_tpu import device_telemetry
+    from lighthouse_tpu.crypto.bls import api
+    from lighthouse_tpu.crypto.bls.backends import host
+    from lighthouse_tpu.ops import verify
+
+    monkeypatch.setattr(verify, "K_BUCKETS", (1, 2, 4))
+    sets = _example_sets(2, n_keys=9, seed=13)
+    bad = list(sets)
+    bad[1] = api.SignatureSet(sets[0].signature, sets[1].message,
+                              sets[1].signing_keys)
+    for batch, want in ((sets, True), (bad, False)):
+        assert host.verify_signature_sets(batch, seed=b"mesh-rows") is want
+        assert verify.verify_signature_sets_device(batch, seed=b"mesh-rows") is want
+        with _mesh():
+            assert verify.verify_signature_sets_device(
+                batch, seed=b"mesh-rows") is want
+            rec = device_telemetry.FLIGHT_RECORDER.recent(1)[0]
+        assert rec["shape"] == "8x4x8x4@dp8" and not rec["host_fallback"]
 
 
 def test_sharded_sha256_pairs_bit_identical_uneven():
