@@ -107,9 +107,15 @@ def iso_map(pt) -> Point:
     return (x2, y2)
 
 
-def hash_to_g2(msg: bytes, dst: bytes) -> Point:
-    """hash_to_curve (random-oracle variant): the signing/verification H(m)."""
+def hash_to_g2_uncleared(msg: bytes, dst: bytes) -> Point:
+    """hash_to_curve up to Q0 + Q1: a point of E2 whose cofactor is not yet
+    cleared (the device verifier clears it, ``ops/ec.g2_clear_cofactor``)."""
     u0, u1 = hash_to_field_fq2(msg, 2, dst)
     q0 = iso_map(map_to_curve_simple_swu(u0))
     q1 = iso_map(map_to_curve_simple_swu(u1))
-    return clear_cofactor_g2(add(q0, q1))
+    return add(q0, q1)
+
+
+def hash_to_g2(msg: bytes, dst: bytes) -> Point:
+    """hash_to_curve (random-oracle variant): the signing/verification H(m)."""
+    return clear_cofactor_g2(hash_to_g2_uncleared(msg, dst))

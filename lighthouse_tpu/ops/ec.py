@@ -21,8 +21,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..crypto.bls import curve as _curve
 from ..crypto.bls import fields as hf
-from ..crypto.bls.params import G1_X, G1_Y, G2_X_C0, G2_X_C1, G2_Y_C0, G2_Y_C1, P
+from ..crypto.bls.params import (G1_X, G1_Y, G2_X_C0, G2_X_C1, G2_Y_C0, G2_Y_C1,
+                                 P, X_ABS)
 from . import fq as _fq
 from . import tower as _tw
 
@@ -279,6 +281,79 @@ def tree_sum(ops: FieldOps, pts, axis: int = 0):
         pts = point_add(ops, tuple(lows), tuple(highs))
         n = half
     return tuple(jnp.squeeze(c, axis=axis) for c in pts)
+
+
+# ------------------------------------------------------- G2 cofactor clearing
+
+_PSI_CX = _tw.fq2_to_limbs(_curve.PSI_CX)
+_PSI_CY = _tw.fq2_to_limbs(_curve.PSI_CY)
+
+
+def _x_abs_runs():
+    """|x| MSB-first after its leading bit, as the doublings before each
+    addition of the base point, then the doublings after the last one."""
+    runs, n = [], 0
+    for bit in bin(X_ABS)[3:]:
+        n += 1
+        if bit == "1":
+            runs.append(n)
+            n = 0
+    return tuple(runs) + (n,)
+
+
+#: (1, 2, 3, 9, 32, 16): 63 doublings; the base point is added after every
+#: run but the last.
+_X_ABS_RUNS = _x_abs_runs()
+
+
+def g2_psi(p):
+    """The endomorphism psi on projective G2 points:
+    (cx·conj(X) : cy·conj(Y) : conj(Z)), ``curve.psi`` on each affine point."""
+    x, y, z = p
+    (px, py), _ = _tw.fq2_many([(_tw.fq2_conj(x), jnp.asarray(_PSI_CX)),
+                                (_tw.fq2_conj(y), jnp.asarray(_PSI_CY))])
+    return px, py, _tw.fq2_conj(z)
+
+
+def _g2_mul_by_x_abs(p):
+    """[|x|]P by the fixed double-and-add of |x| = 0xd201000000010000: a
+    loop over its runs of doublings, each run a loop of its own length
+    followed by one addition of P (of the identity after the last run), so
+    no bit selects between an addition and none.  Two loop bodies keep the
+    traced graph small."""
+    runs = jnp.asarray(_X_ABS_RUNS, jnp.int32)
+    last = len(_X_ABS_RUNS) - 1
+    ident = identity(G2_OPS, p[0].shape[:-2])
+
+    def double(_, acc):
+        return point_double(G2_OPS, acc)
+
+    def run(k, acc):
+        acc = jax.lax.fori_loop(0, runs[k], double, acc)
+        return point_add(G2_OPS, acc, point_select(k < last, p, ident))
+
+    return jax.lax.fori_loop(0, last + 1, run, p)
+
+
+def g2_clear_cofactor(p):
+    """h_eff·P for projective points of E2, batched over leading axes: the
+    value of ``curve.clear_cofactor_g2``, [x²−x−1]P + [x−1]psi(P) + psi²(2P),
+    by the chain of RFC 9380 Appendix G.3 (``clear_cofactor_bls12381_g2``):
+    t1 = [x]P, q = t1 + psi(P), t2 = [x]q, then t2 − q − P + psi²(2P).
+    One loop body serves both multiplications by x, and its two additions
+    run as one on a stacked axis, which keeps the traced graph small."""
+    psi_p = g2_psi(p)
+
+    def step(_, carry):  # q: P, then t1 + psi(P); acc: psi²(2P), less P, q
+        q, acc, _ = carry
+        r = point_neg(_g2_mul_by_x_abs(q))  # [x]q, x < 0
+        both = point_add(G2_OPS, tuple(map(jnp.stack, zip(r, acc))),
+                         tuple(map(jnp.stack, zip(psi_p, point_neg(q)))))
+        return tuple(c[0] for c in both), tuple(c[1] for c in both), r
+
+    acc0 = g2_psi(g2_psi(point_double(G2_OPS, p)))
+    _, acc, t2 = jax.lax.fori_loop(0, 2, step, (p, acc0, p))
+    return point_add(G2_OPS, acc, t2)
 
 
 # ------------------------------------------------------------ host conversion

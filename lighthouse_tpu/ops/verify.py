@@ -7,10 +7,11 @@ reference's hot loop (``crypto/bls/src/impls/blst.rs:35-117``):
 
 entirely on device: per-set pubkey aggregation (complete-formula tree sum over a
 padded key axis), 64-bit random-weight scalar multiplications on G1 and G2, the
-batched Miller loop, one shared final exponentiation.  The host side keeps
-exactly the responsibilities the reference keeps on the "trusted" side:
-CSPRNG weights (blst.rs:52-57 — randomness must not come from the device),
-signature subgroup/infinity checks, hash-to-curve (SHA-256), shape bucketing.
+cofactor clearing of each message's hash, the batched Miller loop, one shared
+final exponentiation.  The host side keeps the responsibilities the reference
+keeps on the "trusted" side: CSPRNG weights (blst.rs:52-57 — randomness must
+not come from the device), signature subgroup/infinity checks, hash-to-curve
+up to the point of E2 before its cofactor is cleared, shape bucketing.
 
 Shape discipline: programs are compiled per (n_sets_bucket, max_keys_bucket);
 batches are padded with identity points + zero weights, which flow through the
@@ -43,7 +44,7 @@ from .. import autotune
 from ..crypto.bls import curve
 from ..crypto.bls.backends.host import _rand_scalars
 from ..crypto.bls.fields import Fq2
-from ..crypto.bls.hash_to_curve import hash_to_g2
+from ..crypto.bls.hash_to_curve import hash_to_g2_uncleared
 from ..crypto.bls.params import DST, G1_X, G1_Y, P
 from . import ec, fq, pairing, tower
 
@@ -105,6 +106,17 @@ def _set_sums(row_sums, rows):
     return ec.tree_sum(ec.G1_OPS, pts, axis=1)
 
 
+def _h2c_clear(msg):
+    """H(m_i) = h_eff·Q_i for the affine points Q_i of ``msg``, affine again
+    (one batched inversion): the host hashes each message to E2 and stops
+    there.  Pad lanes (the generator) are cleared too; ``live`` masks them
+    out of the pairing."""
+    one = jnp.broadcast_to(tower.FQ2_ONE, msg[0].shape)
+    h = ec.g2_clear_cofactor((msg[0], msg[1], one))
+    zi = tower.fq2_inv(h[2])
+    return tuple(tower.fq2_mul_many([(h[0], zi), (h[1], zi)]))
+
+
 @jax.jit
 def _device_verify(pk, sig, msg, wbits, live, rows=None):
     """The fused device program.
@@ -112,7 +124,8 @@ def _device_verify(pk, sig, msg, wbits, live, rows=None):
     pk:   G1 projective, coords (R, K, 25) — key rows, identity-padded; row i
           is set i's keys (R == N) unless ``rows`` is given
     sig:  G2 projective, coords (N, 2, 25)
-    msg:  G2 affine hash points, coords (N, 2, 25)
+    msg:  G2 affine points Q0 + Q1 of each message's hash before its
+          cofactor is cleared, coords (N, 2, 25)
     wbits:(N, 64) int32, MSB-first random weights (zero rows for padding)
     live: (N,) bool
     rows: None, or (N, RB) int32: the key rows of each set, -1 past its last
@@ -121,8 +134,8 @@ def _device_verify(pk, sig, msg, wbits, live, rows=None):
 
     The stages carry named scopes (HLO metadata only), which
     ``device_telemetry.program_stages`` maps the compiled instructions to:
-    s1-s3 here (``s1_set_sum`` only where ``rows`` is given), ``miller_loop``
-    and ``final_exp`` (s4, s5) in the pairing.
+    s1-s3 and ``h2c_clear`` here (``s1_set_sum`` only where ``rows`` is
+    given), ``miller_loop`` and ``final_exp`` (s4, s5) in the pairing.
     """
     with jax.named_scope("s1_key_sum"):
         agg = ec.tree_sum(ec.G1_OPS, pk, axis=1)              # (R,) G1 proj
@@ -142,21 +155,23 @@ def _device_verify(pk, sig, msg, wbits, live, rows=None):
         # check).
         zi = tower.fq2_inv(w[2])
         w_aff = (tower.fq2_mul(w[0], zi), tower.fq2_mul(w[1], zi))
+    with jax.named_scope("h2c_clear"):
+        h_aff = _h2c_clear(msg)
 
     # Assemble N+1 pairs: ( [r_i]aggpk_i, H_i ) ... ( -g1, W ).
     def cat(a, b):
         return jnp.concatenate([a, b[None]], axis=0)
 
     p1 = tuple(cat(p_weighted[i], jnp.asarray(_NEG_G1[i])) for i in range(3))
-    q2 = tuple(cat(msg[i], w_aff[i]) for i in range(2))
+    q2 = tuple(cat(h_aff[i], w_aff[i]) for i in range(2))
     mask = jnp.concatenate([live, jnp.asarray([True])])
     fe = pairing.multi_pairing_fe(p1, q2, mask)
     return fe, w[2]
 
 
-#: The named scopes of ``_device_verify``'s stages s1-s5, in program order.
+#: The named scopes of ``_device_verify``'s stages, in program order.
 STAGES = ("s1_key_sum", "s1_set_sum", "s2_g1_ladder", "s3_g2_msm",
-          "miller_loop", "final_exp")
+          "h2c_clear", "miller_loop", "final_exp")
 
 
 def bucket_shape(batch) -> Tuple[int, ...]:
@@ -185,15 +200,16 @@ def lower_for_stages(shape: Tuple[int, ...]):
 _hash_cache: dict = {}
 
 
-def _hash_to_g2_cached(message: bytes, misses: Optional[list] = None):
-    """``hash_to_g2`` behind a bounded cache; a miss appends the message to
-    ``misses`` when given (the marshal span's ``n_hash_misses``)."""
+def _hash_to_g2_uncleared_cached(message: bytes,
+                                 misses: Optional[list] = None):
+    """``hash_to_g2_uncleared`` behind a bounded cache; a miss appends the
+    message to ``misses`` when given (the marshal span's ``n_hash_misses``)."""
     key = bytes(message)
     pt = _hash_cache.get(key)
     if pt is None:
         if misses is not None:
             misses.append(key)
-        pt = hash_to_g2(key, DST)
+        pt = hash_to_g2_uncleared(key, DST)
         if len(_hash_cache) > 4096:
             _hash_cache.clear()
         _hash_cache[key] = pt
@@ -333,7 +349,7 @@ def build_batch(sets, rands) -> Optional[tuple]:
 
     Three contiguous phases, each a span under the caller's
     ``device_batch_setup``: every signature's checks before any hashing
-    (``marshal_subgroup``), every message's hash (``marshal_hash_to_g2``),
+    (``marshal_subgroup``), every message's hash to E2 (``marshal_hash_to_g2``),
     then the limbs of all sets (``marshal_pack``).
     """
     from .. import tracing
@@ -358,9 +374,10 @@ def build_batch(sets, rands) -> Optional[tuple]:
             if not s.signing_keys:
                 return None
 
-    with tracing.span("marshal_hash_to_g2", n_messages=n) as sp_hash:
+    with tracing.span("marshal_hash_to_g2", n_messages=n,
+                      cleared_on_device=n) as sp_hash:
         misses: list = []
-        hashes = [_hash_to_g2_cached(s.message, misses) for s in sets]
+        hashes = [_hash_to_g2_uncleared_cached(s.message, misses) for s in sets]
         sp_hash.fields["n_hash_misses"] = len(misses)
 
     n_keys = int(counts.sum())

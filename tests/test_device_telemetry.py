@@ -457,7 +457,7 @@ class TestProgramStages:
         finally:
             jax.monitoring.unregister_event_duration_listener(hear)
         assert compiles == []
-        assert {"s1_key_sum", "s2_g1_ladder", "s3_g2_msm", "miller_loop",
-                "final_exp"} == set(stages.values())
+        assert {"s1_key_sum", "s2_g1_ladder", "s3_g2_msm", "h2c_clear",
+                "miller_loop", "final_exp"} == set(stages.values())
         assert device_telemetry.program_stages("bls_verify", (2, 2)) is stages
         assert device_telemetry.program_stages("epoch_deltas", (8,)) is None

@@ -1,5 +1,6 @@
 """The host marshal of ``ops/verify.build_batch``: its phases (subgroup
-checks, hashing, packing) return the same arrays as packing set by set, a
+checks, hashing to E2 with the cofactor left for the device, packing)
+return the same arrays as packing set by set, a
 set wider than the top key bucket is laid over key rows of that width, and
 a bad signature anywhere stops the batch before any message is hashed."""
 
@@ -12,6 +13,7 @@ from lighthouse_tpu import tracing
 from lighthouse_tpu.crypto.bls import api, curve
 from lighthouse_tpu.crypto.bls.backends.host import _rand_scalars
 from lighthouse_tpu.crypto.bls.fields import Fq
+from lighthouse_tpu.crypto.bls.hash_to_curve import hash_to_g2_uncleared
 from lighthouse_tpu.crypto.bls.params import P
 from lighthouse_tpu.ops import ec, tower
 from lighthouse_tpu.ops import verify as verify_mod
@@ -91,7 +93,7 @@ def _reference_pack(sets, rands):
         sig_pt = s.signature.point
         assert sig_pt is not None and curve.in_g2(sig_pt) and s.signing_keys
         sl = ec.g2_to_limbs(sig_pt)
-        h = verify_mod.hash_to_g2(bytes(s.message), verify_mod.DST)
+        h = hash_to_g2_uncleared(bytes(s.message), verify_mod.DST)
         for c in range(3):
             sig[c][i] = sl[c]
         msg[0][i] = tower.fq2_to_limbs(h[0])
@@ -135,6 +137,7 @@ def test_phased_marshal_matches_set_by_set_packing(make_sets, shape):
     assert names == ["marshal_subgroup", "marshal_hash_to_g2", "marshal_pack"]
     hashed = root.children[1]
     assert hashed.fields["n_messages"] == len(sets)
+    assert hashed.fields["cleared_on_device"] == len(sets)
     assert root.children[2].fields["n_keys"] == sum(
         len(s.signing_keys) for s in sets)
     assert root.children[2].fields["n_rows"] == len(sets)
@@ -175,19 +178,21 @@ def test_hash_misses_are_counted_where_the_cache_is_consulted(monkeypatch):
     sets = _mixed_sets()
     with tracing.span("unit_marshal") as root:
         verify_mod.build_batch(sets, _rand_scalars(len(sets), b"s"))
-    # three distinct messages among four sets
+    # three distinct messages among four sets; the device clears all four
     assert root.children[1].fields["n_hash_misses"] == 3
+    assert root.children[1].fields["cleared_on_device"] == 4
     with tracing.span("unit_marshal_again") as again:
         verify_mod.build_batch(sets, _rand_scalars(len(sets), b"s"))
     assert again.children[1].fields["n_hash_misses"] == 0
+    assert again.children[1].fields["cleared_on_device"] == 4
 
 
 @pytest.mark.parametrize("bad", ["missing", "no_keys"])
 def test_bad_last_set_returns_none_before_any_hashing(monkeypatch, bad):
     monkeypatch.setattr(verify_mod, "_hash_cache", {})
     hashed = []
-    real = verify_mod.hash_to_g2
-    monkeypatch.setattr(verify_mod, "hash_to_g2",
+    real = verify_mod.hash_to_g2_uncleared
+    monkeypatch.setattr(verify_mod, "hash_to_g2_uncleared",
                         lambda m, dst: hashed.append(m) or real(m, dst))
     sets = _mixed_sets()
     last = sets[-1]
