@@ -595,7 +595,7 @@ def _mesh_measure(n_sets: int, mesh_spec, seed: int) -> dict:
 
     from __graft_entry__ import _build_example
     from lighthouse_tpu import device_mesh
-    from lighthouse_tpu.ops import verify
+    from lighthouse_tpu.ops import dispatch, verify
     from lighthouse_tpu.ops.pairing import fe_is_one
 
     device_mesh.reset_for_tests()
@@ -603,12 +603,13 @@ def _mesh_measure(n_sets: int, mesh_spec, seed: int) -> dict:
         device_mesh.configure(str(mesh_spec))
     host_batch = _build_example(n_sets=n_sets, n_keys=2, seed=seed,
                                 tile_base=min(n_sets, 16))
-    placed, mesh, _ = verify.place_batch(host_batch)
+    batch = dispatch.Placed(verify.PROGRAM, host_batch)
+    placed, mesh = batch.args, batch.mesh
     lead = placed[0][0]
     if mesh:
         rows = sorted((s.data.shape[0] for s in lead.addressable_shards),
                       reverse=True)
-        fn = verify._sharded_entry().callable()
+        fn = verify.PROGRAM.entry.callable()
     else:
         rows = [int(lead.shape[0])]
         fn = verify._device_verify

@@ -263,14 +263,14 @@ def phase_four_chips(n_att: int, att_keys: int, sync_keys: int,
                                api.verify_signature_sets(bad_sets))
     assert verdicts["mesh"] == verdicts["one_device"] == (True, False), verdicts
     built = build_device_batch(sets)
-    shards = built.batch[0][0].addressable_shards
+    shards = built.args[0][0].addressable_shards
     devices = sorted(int(s.device.id) for s in shards)
     rows = [int(s.data.shape[0]) for s in shards]
     assert len(set(devices)) == n_devices == len(shards), \
         f"shards on devices {devices}"
-    assert all(r == built.nb // n_devices for r in rows), rows
+    assert all(r == built.shape[0] // n_devices for r in rows), rows
     return {"verdicts": verdicts, "shard_devices": devices,
-            "shard_rows": rows, "bucket": [built.nb, built.kb]}
+            "shard_rows": rows, "bucket": list(built.shape[:2])}
 
 
 # -------------------------------------------------------------------- main

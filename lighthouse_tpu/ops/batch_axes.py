@@ -1,7 +1,7 @@
 """Batch-axis registry: the sharding contract of every device entry point.
 
-ROADMAP item 1 (the mesh in the production dispatch path — landed:
-``lighthouse_tpu/device_mesh.py``) shards the *batch axis* of the bucketed
+ROADMAP item 1 (the mesh in the production dispatch path — landed: the
+mesh layer behind ``ops/dispatch.py``) shards the *batch axis* of the bucketed
 device programs over a ``jax.sharding.Mesh``.  That only works if the
 batch axis is a real, declared property of each entry point — not folklore
 living in docstrings.  This registry IS that declaration: one entry per
@@ -18,8 +18,8 @@ Consumed three ways:
   of ``lighthouse_tpu``) and fails when a jitted entry point in ``ops/`` is
   missing here, or when code inside a registered entry folds the batch
   axis into limb axes;
-- ``device_mesh.ShardedEntry`` — the consumer this registry was written
-  for — derives each entry's ``NamedSharding``/``PartitionSpec``\\ s
+- the mesh layer's ``ShardedEntry``, one per program of ``ops/dispatch.py``
+  — the consumer this registry was written for — derives each entry's ``NamedSharding``/``PartitionSpec``\\ s
   mechanically from ``batch_axis``/``batched_args``/``replicated_args``/
   ``out_batched`` instead of hand-maintaining them;
 - the HLO budget auditor (``scripts/analysis/hlo_budget.py``) keys its

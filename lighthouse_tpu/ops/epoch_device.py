@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import autotune
+from . import dispatch
 from ..types.spec import (
     PARTICIPATION_FLAG_WEIGHTS,
     TIMELY_HEAD_FLAG_INDEX,
@@ -183,12 +184,6 @@ def _balance_core(
     return new_eff, ejection_mask, queue_mask, activation_mask
 
 
-#: device_mesh.ShardedEntry for the epoch kernel (lazy).  The kernel's
-#: registry-wide participating-increment sums lower through XLA-inserted
-#: psums on the mesh — which is exactly why the op sits in
-#: ``device_supervisor.NO_SPLIT_OPS``.
-_SHARDED_ENTRY = None
-
 ENTRY_KEY = "lighthouse_tpu/ops/epoch_device.py:_deltas_kernel"
 
 #: Epoch far beyond any reachable epoch: bucket- and mesh-pad rows use it
@@ -240,16 +235,11 @@ def _bucket(n: int) -> int:
     return n
 
 
-def _sharded_entry():
-    global _SHARDED_ENTRY
-    if _SHARDED_ENTRY is None:
-        from .. import device_mesh
-
-        _SHARDED_ENTRY = device_mesh.ShardedEntry(
-            ENTRY_KEY, _deltas_kernel.__wrapped__,
-            static_argnames=("in_leak",),
-        )
-    return _SHARDED_ENTRY
+#: The deltas kernel behind the dispatch seam.  Its registry-wide
+#: participating-increment sums lower through XLA-inserted psums on the
+#: mesh — which is exactly why the op sits in
+#: ``device_supervisor.NO_SPLIT_OPS``.
+PROGRAM = dispatch.Program(_deltas_kernel, fills=_PAD_FILLS, x64=True)
 
 
 def epoch_deltas_device(
@@ -272,71 +262,26 @@ def epoch_deltas_device(
     rows (far-future activation: ineligible for every flag mask, so the
     participating-increment sums/psums are untouched); the pad rows are
     sliced back off the outputs."""
-    import time as _time
-
-    from .. import device_mesh, device_telemetry, fault_injection
-
     # One executable per (registry-bucket, in_leak) pair — in_leak is a
     # static argument, so it forks the compiled program like a shape does.
-    op = "epoch_deltas_leak" if in_leak else "epoch_deltas"
     n = int(np.asarray(arrays.effective_balance).shape[0])
-    nb = _bucket(n)
-    mesh = device_mesh.size() if device_mesh.enabled() else 0
-    np_ = device_mesh.pad_rows(nb) if mesh else nb
-    if fault_injection.ACTIVE:
-        if not device_telemetry.COMPILE_CACHE.seen(op, (np_,), mesh=mesh):
-            fault_injection.check("device.compile", op=op)
-        fault_injection.check("device.dispatch", op=op)
-    with jax.enable_x64(True):
-        batched = (
-            np.asarray(arrays.effective_balance, dtype=np.int64),
-            np.asarray(arrays.activation_epoch, dtype=np.int64),
-            np.asarray(arrays.exit_epoch, dtype=np.int64),
-            np.asarray(arrays.withdrawable_epoch, dtype=np.int64),
-            np.asarray(arrays.slashed, dtype=bool),
-            np.asarray(prev_part, dtype=np.int64),
-            np.asarray(inactivity, dtype=np.int64),
-        )
-        scalars = (
-            previous_epoch, base_reward_per_increment, total_active_balance,
-            spec.effective_balance_increment, spec.inactivity_score_bias,
-            spec.inactivity_score_recovery_rate, quotient,
-        )
-        if np_ != n:
-            batched = tuple(
-                device_mesh.grow_rows(a, np_, f)
-                for a, f in zip(batched, _PAD_FILLS)
-            )
-        t_dispatch = _time.perf_counter()
-        if mesh:
-            entry = _sharded_entry()
-            placed = entry.place(
-                *batched, *(jnp.int64(s) for s in scalars)
-            )
-            out = entry(*placed, in_leak=bool(in_leak))
-        else:
-            out = _deltas_kernel(
-                *(jnp.asarray(a) for a in batched),
-                *(jnp.int64(s) for s in scalars),
-                in_leak=bool(in_leak),
-            )
-        dispatch_s = _time.perf_counter() - t_dispatch
-        compiled = device_telemetry.note_dispatch(op, (np_,), dispatch_s,
-                                                 mesh=mesh)
-        t_wait = _time.perf_counter()
-        new_inactivity, balance_delta = jax.device_get(out)
-    device_telemetry.record_batch(
-        op=op,
-        shape=(np_,),
-        n_live=n,
-        stages={"dispatch": dispatch_s,
-                "wait": _time.perf_counter() - t_wait},
-        trace_id=device_telemetry.active_trace_id(),
-        compiled=compiled,
-        mesh=mesh,
-        shard_live=(_sharded_entry().shard_live_counts(n, np_)
-                    if mesh else None),
-    )
+    host = (
+        np.asarray(arrays.effective_balance, dtype=np.int64),
+        np.asarray(arrays.activation_epoch, dtype=np.int64),
+        np.asarray(arrays.exit_epoch, dtype=np.int64),
+        np.asarray(arrays.withdrawable_epoch, dtype=np.int64),
+        np.asarray(arrays.slashed, dtype=bool),
+        np.asarray(prev_part, dtype=np.int64),
+        np.asarray(inactivity, dtype=np.int64),
+    ) + tuple(np.int64(s) for s in (
+        previous_epoch, base_reward_per_increment, total_active_balance,
+        spec.effective_balance_increment, spec.inactivity_score_bias,
+        spec.inactivity_score_recovery_rate, quotient,
+    ))
+    new_inactivity, balance_delta = PROGRAM.call(
+        host, n_live=n, rows=_bucket(n),
+        op="epoch_deltas_leak" if in_leak else "epoch_deltas",
+        static={"in_leak": bool(in_leak)})
     return (
         np.asarray(new_inactivity[:n], dtype=np.int64),
         np.asarray(balance_delta[:n], dtype=np.int64),

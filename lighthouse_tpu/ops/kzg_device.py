@@ -20,14 +20,14 @@ golden model this program must agree with exactly):
 
 from __future__ import annotations
 
-import functools
 from typing import Optional, Sequence
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 
-from . import ec, pairing, tower
+from .. import tracing
+from . import dispatch, ec, pairing, tower
 
 N_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512)
 
@@ -97,35 +97,23 @@ def _bucket(n: int) -> int:
     raise ValueError(f"kzg batch of {n} exceeds max bucket {N_BUCKETS[-1]}")
 
 
-#: device_mesh.ShardedEntry for the kzg program (lazy).  The blob-axis
-#: tree-sum lincombs reduce across the mesh through XLA-inserted psums —
-#: the ``reduces_over_batch`` op the registry note promised, and why it
-#: sits in ``device_supervisor.NO_SPLIT_OPS``.
-_SHARDED_ENTRY = None
-
-ENTRY_KEY = "lighthouse_tpu/ops/kzg_device.py:_device_kzg_batch"
-
-
-def _sharded_entry():
-    global _SHARDED_ENTRY
-    if _SHARDED_ENTRY is None:
-        from .. import device_mesh
-
-        _SHARDED_ENTRY = device_mesh.ShardedEntry(
-            ENTRY_KEY, _device_kzg_batch.__wrapped__
-        )
-    return _SHARDED_ENTRY
+#: ``_device_kzg_batch`` behind the dispatch seam.  On a mesh the blob-axis
+#: tree-sum lincombs reduce across devices through XLA-inserted psums — why
+#: the op sits in ``device_supervisor.NO_SPLIT_OPS``; its pad rows
+#: (identity points, zero scalars) add exact neutral elements to them.
+_ID1 = ec.g1_to_limbs(None)
+PROGRAM = dispatch.Program(_device_kzg_batch, fills=(_ID1, _ID1, 0, 0),
+                           spans="kzg_batch")
 
 
 def _build_kzg_batch(c_pts, p_pts, r_powers, zs, ys, g2_tau, nb: int):
     """Host-side marshalling (limb packing, scalar-bit expansion) into
-    padded device arrays — no device work beyond the uploads."""
+    padded host arrays: the arguments of ``_device_kzg_batch``."""
     from ..crypto.bls.params import R
 
     n = len(c_pts)
-    id1 = ec.g1_to_limbs(None)
-    c = [np.tile(np.asarray(x), (nb, 1)) for x in id1]
-    p = [np.tile(np.asarray(x), (nb, 1)) for x in id1]
+    c = [np.tile(np.asarray(x), (nb, 1)) for x in _ID1]
+    p = [np.tile(np.asarray(x), (nb, 1)) for x in _ID1]
     r_bits = np.zeros((nb, 256), np.int32)
     rz_bits = np.zeros((nb, 256), np.int32)
     ry = 0
@@ -145,28 +133,18 @@ def _build_kzg_batch(c_pts, p_pts, r_powers, zs, ys, g2_tau, nb: int):
         np.asarray(ec.G2_GEN_LIMBS[0]),
         np.asarray(ec.G2_GEN_LIMBS[1]),
     )
-    host = (
+    return (
         tuple(c), tuple(p), r_bits, rz_bits,
         np.asarray(ry_bits),
         tuple(np.asarray(a) for a in tau),
         tuple(np.asarray(a) for a in g2gen),
     )
-    from .. import device_mesh
 
-    if device_mesh.enabled():
-        # nb was already padded to a multiple of the mesh by the caller;
-        # the identity-point + zero-scalar pad rows contribute exact
-        # neutral elements to the psum'd lincombs.
-        return _sharded_entry().place(*host)
-    return (
-        tuple(jnp.asarray(a) for a in host[0]),
-        tuple(jnp.asarray(a) for a in host[1]),
-        jnp.asarray(host[2]),
-        jnp.asarray(host[3]),
-        jnp.asarray(host[4]),
-        tuple(jnp.asarray(a) for a in host[5]),
-        tuple(jnp.asarray(a) for a in host[6]),
-    )
+
+def _verdict(fe) -> bool:
+    # ``pairing.fe_is_one`` is looked up per call, so a fault planted there
+    # (``benchmark/faults.py``) reaches the device leg.
+    return pairing.fe_is_one(fe)
 
 
 def verify_kzg_proof_batch_device(
@@ -186,94 +164,21 @@ def verify_kzg_proof_batch_device(
     host MSM golden model in ``crypto/kzg/kzg.py``) under the one shared
     breaker/fallback mechanism.  With ``host_fn=None`` failures propagate.
     """
-    from .. import (device_mesh, device_supervisor, device_telemetry,
-                    fault_injection, tracing)
-
     n = len(c_pts)
     assert n == len(p_pts) == len(r_powers) == len(zs) == len(ys)
-    holder: dict = {}
+    nb = _bucket(max(1, n))
 
-    def device_fn() -> bool:
-        # Runs on the supervisor's worker with the caller's span attached:
-        # the kzg_batch_* spans land in the caller's trace, and their
-        # durations are the flight record's stages.
-        stages_local: dict = {}
-        state_local: dict = {}
-        try:
-            # Marshalling (and its host→device uploads) happens INSIDE the
-            # supervised leg: an OPEN breaker must not touch the device at
-            # all, and a transfer raising on a dead device resolves through
-            # the host fallback like any other device failure.  Bucket and
-            # mesh pad are (re)computed here too, so a supervisor reshard
-            # retry re-places under the surviving topology.
-            with tracing.span("kzg_batch_setup", n_blobs=n) as sp_setup:
-                mesh = device_mesh.size() if device_mesh.enabled() else 0
-                nb = _bucket(max(1, n))
-                if mesh:
-                    nb = device_mesh.pad_rows(nb)
-                state_local["mesh"], state_local["nb"] = mesh, nb
-                batch = _build_kzg_batch(c_pts, p_pts, r_powers, zs, ys,
-                                         g2_tau, nb)
-            stages_local["setup"] = sp_setup.duration
-            if fault_injection.ACTIVE:
-                if not device_telemetry.COMPILE_CACHE.seen("kzg_batch", (nb,),
-                                                           mesh=mesh):
-                    fault_injection.check("device.compile", op="kzg_batch")
-                fault_injection.check("device.dispatch", op="kzg_batch")
-            with tracing.span("kzg_batch_dispatch", n_bucket=nb,
-                              mesh=mesh) as sp_dispatch:
-                if mesh:
-                    fe = _sharded_entry()(*batch)
-                else:
-                    fe = _device_kzg_batch(*batch)
-            stages_local["dispatch"] = sp_dispatch.duration
-            if device_telemetry.note_dispatch("kzg_batch", (nb,),
-                                              sp_dispatch.duration, mesh=mesh):
-                sp_dispatch.fields["compiled"] = True
-                state_local["compiled"] = True
-            with tracing.span("kzg_batch_wait", n_bucket=nb) as sp_wait:
-                jax.block_until_ready(fe)
-            stages_local["wait"] = sp_wait.duration
-            with tracing.span("kzg_batch_verdict") as sp_verdict:
-                try:
-                    return pairing.fe_is_one(fe)
-                finally:
-                    stages_local["verdict"] = sp_verdict.duration
-        finally:
-            holder["stages"] = stages_local
-            holder["state"] = state_local
+    def setup() -> dispatch.Placed:
+        # Marshalling and its uploads run on the supervised leg: an OPEN
+        # breaker must not touch the device at all, a transfer raising on a
+        # dead device resolves through the host fallback like any other
+        # device failure, and a reshard retry places under the surviving
+        # topology.
+        with tracing.span("kzg_batch_setup", n_blobs=n) as sp_setup:
+            placed = dispatch.Placed(PROGRAM, _build_kzg_batch(
+                c_pts, p_pts, r_powers, zs, ys, g2_tau, nb))
+        placed.setup_s = sp_setup.duration
+        return placed
 
-    info: dict = {}
-    ok = device_supervisor.run(
-        "kzg_batch",
-        device_fn,
-        host_fn=host_fn,
-        info=info,
-    )
-    reason = info.get("fallback_reason")
-    stages: dict = {}
-    compiled = False
-    state: dict = {}
-    if reason != "dispatch_timeout":
-        stages = holder.get("stages") or {}
-        state = holder.get("state") or {}
-        compiled = state.get("compiled", False)
-    mesh = state.get("mesh", 0)
-    nb = state.get("nb", _bucket(max(1, n)))
-    device_telemetry.record_batch(
-        op="kzg_batch",
-        shape=(nb,),
-        n_live=n,
-        stages=stages or None,
-        verdict=bool(ok),
-        host_fallback=info.get("route") == "host",
-        fallback_reason=reason,
-        trace_id=device_telemetry.active_trace_id(),
-        compiled=compiled,
-        breaker_state=info.get("breaker_state"),
-        dispatched=reason != "breaker_open",
-        mesh=mesh,
-        shard_live=(_sharded_entry().shard_live_counts(n, nb)
-                    if mesh else None),
-    )
-    return bool(ok)
+    return bool(PROGRAM.run(setup, n_live=n, host_fn=host_fn,
+                            verdict=_verdict, bucket=(nb,)))

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import autotune
+from . import dispatch
 
 _K = np.array([
     0x428a2f98, 0x71374491, 0xb5c0fbcf, 0xe9b5dba5, 0x3956c25b, 0x59f111f1,
@@ -70,23 +71,6 @@ autotune.register_vocabulary(
     budget_key=lambda nb: f"sha256_pairs|-|{nb}|-",
     warmup=_aot_warmup,
 )
-
-
-#: device_mesh.ShardedEntry for the pair-hash kernel (lazy).
-_SHARDED_ENTRY = None
-
-ENTRY_KEY = "lighthouse_tpu/ops/sha256_device.py:_sha256_64byte_batch"
-
-
-def _sharded_entry():
-    global _SHARDED_ENTRY
-    if _SHARDED_ENTRY is None:
-        from .. import device_mesh
-
-        _SHARDED_ENTRY = device_mesh.ShardedEntry(
-            ENTRY_KEY, _sha256_64byte_batch.__wrapped__
-        )
-    return _SHARDED_ENTRY
 
 
 def _rotr(x, n):
@@ -142,6 +126,11 @@ def _sha256_64byte_batch(words):
     pad = jnp.broadcast_to(jnp.asarray(_PAD_WORDS, dtype=jnp.uint32), (n, 16))
     state = _compress(state, pad)
     return state
+
+
+#: The pair-hash kernel behind the dispatch seam: every 64-byte block is
+#: independent, so a mesh splits the blocks with no collectives.
+PROGRAM = dispatch.Program(_sha256_64byte_batch, fills=(0,))
 
 
 def _bucket(n: int, buckets: Optional[Sequence[int]] = None) -> int:
@@ -202,45 +191,15 @@ def _host_hash_pairs(data: bytes) -> bytes:
     return ssz_mod._hash_pairs(data)
 
 
-def _dispatch_batch(words: np.ndarray, nb: int, stages: dict,
-                    state: dict) -> np.ndarray:
-    """Dispatch + wait on the supervisor's watchdog worker.
+def _words(data: bytes, n: int) -> np.ndarray:
+    """``n`` 64-byte blocks as big-endian words, padded to their bucket."""
+    buf = np.zeros((_bucket(n), 64), dtype=np.uint8)
+    buf[:n] = np.frombuffer(data[: n * 64], dtype=np.uint8).reshape(n, 64)
+    return buf.view(">u4").astype(np.uint32)
 
-    Mesh on: the word block pads to a multiple of the mesh size, uploads
-    through the mesh placer and runs the sharded lowering (every 64-byte
-    block is independent — pure data parallelism, no collectives); mesh
-    off: the original single-device dispatch, untouched."""
-    import time as _time
 
-    from .. import device_mesh, device_telemetry, fault_injection
-
-    mesh = 0
-    if device_mesh.enabled():
-        mesh = device_mesh.size()
-        nbp = device_mesh.pad_rows(nb)
-        words, nb = device_mesh.grow_rows(words, nbp, 0), nbp
-        state["mesh"], state["nb"] = mesh, nb
-        (placed,) = _sharded_entry().place(words)
-    if fault_injection.ACTIVE:
-        if not device_telemetry.COMPILE_CACHE.seen("sha256_pairs", (nb,),
-                                                   mesh=mesh):
-            fault_injection.check("device.compile", op="sha256_pairs")
-        fault_injection.check("device.dispatch", op="sha256_pairs")
-    t_dispatch = _time.perf_counter()
-    if mesh:
-        dev_out = _sharded_entry()(placed)
-    else:
-        # recompile-hazard: ok(nb is bucket-quantized; the mesh branch above only pads to the mesh multiple)
-        dev_out = _sha256_64byte_batch(jnp.asarray(words))
-    dispatch_s = _time.perf_counter() - t_dispatch
-    stages["dispatch"] = dispatch_s
-    if device_telemetry.note_dispatch("sha256_pairs", (nb,), dispatch_s,
-                                      mesh=mesh):
-        state["compiled"] = True
-    t_wait = _time.perf_counter()
-    out = np.asarray(dev_out)
-    stages["wait"] = _time.perf_counter() - t_wait
-    return out
+def _digests(out: np.ndarray, n: int) -> bytes:
+    return out[:n].astype(">u4").tobytes()
 
 
 def hash_pairs_device(data: bytes) -> bytes:
@@ -253,84 +212,16 @@ def hash_pairs_device(data: bytes) -> bytes:
     Supervised (device_supervisor.py): a hung or failing device batch
     resolves through the host SHA kernel, split-retried once first — each
     64-byte block is independent, so halves concatenate exactly."""
-    from .. import device_supervisor, device_telemetry
-
     n = len(data) // 64
     if n == 0:
         return b""
-    nb = _bucket(n)
-    buf = np.zeros((nb, 64), dtype=np.uint8)
-    buf[:n] = np.frombuffer(data[: n * 64], dtype=np.uint8).reshape(n, 64)
-    words = buf.view(">u4").astype(np.uint32)  # big-endian words
-    # Worker-owned stage dicts, published when the device fn finishes (see
-    # verify.py): sharing them with an abandoned watchdog worker would race
-    # record_batch's iteration after a dispatch timeout.
-    holder: dict = {}
-
-    def device_fn() -> bytes:
-        stages_local: dict = {}
-        state_local: dict = {}
-        try:
-            out = _dispatch_batch(words, nb, stages_local, state_local)
-            return out[:n].astype(">u4").tobytes()
-        finally:
-            holder["stages"] = stages_local
-            holder["state"] = state_local
-
-    def _device_half(chunk: bytes) -> bytes:
-        # Raw device path for one half — must NOT recurse into the
-        # supervised entry point (the halves already run on the watchdog
-        # worker; re-entering run() would submit to the busy worker).
-        m = len(chunk) // 64
-        nbh = _bucket(m)
-        half = np.zeros((nbh, 64), dtype=np.uint8)
-        half[:m] = np.frombuffer(chunk, dtype=np.uint8).reshape(m, 64)
-        out = _dispatch_batch(
-            half.view(">u4").astype(np.uint32), nbh, {}, {}
-        )
-        return out[:m].astype(">u4").tobytes()
-
-    def split_fn():
-        mid = n // 2
-        if mid == 0:
-            raise ValueError("single-block batch cannot split")
-        return [
-            lambda: _device_half(data[: mid * 64]),
-            lambda: _device_half(data[mid * 64: n * 64]),
-        ]
-
-    info: dict = {}
-    out_bytes = device_supervisor.run(
-        "sha256_pairs",
-        device_fn,
-        host_fn=lambda: _host_hash_pairs(data),
-        split_fn=split_fn,
-        combine_fn=b"".join,
-        info=info,
-    )
-    reason = info.get("fallback_reason")
-    stages: dict = {}
-    compiled = False
-    state: dict = {}
-    if reason != "dispatch_timeout":
-        stages = holder.get("stages") or {}
-        state = holder.get("state") or {}
-        compiled = state.get("compiled", False)
-    mesh = state.get("mesh", 0)
-    nbp = state.get("nb", nb)  # mesh-divisibility pad, if any
-    device_telemetry.record_batch(
-        op="sha256_pairs",
-        shape=(nbp,),
+    words = _words(data, n)
+    return PROGRAM.run(
+        lambda: dispatch.Placed(PROGRAM, (words,)),
         n_live=n,
-        stages=stages or None,
-        host_fallback=info.get("route") == "host",
-        fallback_reason=reason,
-        trace_id=device_telemetry.active_trace_id(),
-        compiled=compiled,
-        breaker_state=info.get("breaker_state"),
-        dispatched=reason != "breaker_open",
-        mesh=mesh,
-        shard_live=(_sharded_entry().shard_live_counts(n, nbp)
-                    if mesh else None),
+        host_fn=lambda: _host_hash_pairs(data),
+        finish=_digests,
+        half=lambda lo, hi: (_words(data[lo * 64:], hi - lo),),
+        combine=b"".join,
+        bucket=(words.shape[0],),
     )
-    return out_bytes

@@ -39,7 +39,6 @@ scoped ``jax.enable_x64`` context like the deltas pass.
 
 from __future__ import annotations
 
-import threading
 from dataclasses import dataclass
 from functools import partial
 from hashlib import sha256
@@ -51,6 +50,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import autotune
+from . import dispatch
 from .epoch_device import _PAD_ACTIVATION_EPOCH, _balance_core, _deltas_core
 from .sha256_device import _H0, _compress
 
@@ -64,14 +64,6 @@ N_BUCKETS = (64, 256, 1024, 4096, 16384, 65536, 262144, 1048576)
 #: probability below (31/32)^64 ~= 13% worst-case and ~1e-9 at mainnet
 #: balances; a not-found slot simply stays on the scalar spec walk.
 PROPOSER_CANDIDATES = 64
-
-_ENTRY_LOCK = threading.Lock()
-
-#: device_mesh.ShardedEntry for the fused boundary kernel (lazy; guarded
-#: by _ENTRY_LOCK — dispatches can race in from scheduler workers).
-_SHARDED_ENTRY = None
-
-ENTRY_KEY = "lighthouse_tpu/ops/shuffle_device.py:_boundary_kernel"
 
 #: Per-pad-row fills for the boundary's batched argument tuple (eff_bal,
 #: activation, exit, withdrawable, slashed, prev_part, inactivity,
@@ -297,17 +289,13 @@ def _bucket(op: str, n: int) -> int:
     return n
 
 
-def _sharded_entry():
-    global _SHARDED_ENTRY
-    with _ENTRY_LOCK:
-        if _SHARDED_ENTRY is None:
-            from .. import device_mesh
-
-            _SHARDED_ENTRY = device_mesh.ShardedEntry(
-                ENTRY_KEY, _boundary_kernel.__wrapped__,
-                static_argnames=("in_leak",),
-            )
-        return _SHARDED_ENTRY
+#: The three kernels behind the dispatch seam.  Only the fused boundary
+#: shards over a mesh; the shuffle and the proposer walk always dispatch on
+#: one device.
+SHUFFLE = dispatch.Program(_shuffle_kernel)
+PROPOSER = dispatch.Program(_proposer_kernel, x64=True,  # bucket: eff_act
+                            shape=lambda args: (int(args[3].shape[0]),))
+BOUNDARY = dispatch.Program(_boundary_kernel, fills=_PAD_FILLS, x64=True)
 
 
 # ------------------------------------------------- host-side table builds
@@ -365,43 +353,15 @@ def proposer_tables(
 def shuffle_device(values, seed: bytes, rounds: int) -> np.ndarray:
     """Device ``shuffle_list``: numpy in, numpy out, bit-identical to the
     host path (``out[i] = values[compute_shuffled_index(i)]``)."""
-    import time as _time
-
-    from .. import device_telemetry, fault_injection
-
-    op = "shuffle"
     arr = np.asarray(values)
     n = int(arr.shape[0])
     if n <= 1 or rounds == 0:
         return arr.copy()
-    nb = _bucket(op, n)
-    if fault_injection.ACTIVE:
-        if not device_telemetry.COMPILE_CACHE.seen(op, (nb,), mesh=0):
-            fault_injection.check("device.compile", op=op)
-        fault_injection.check("device.dispatch", op=op)
+    nb = _bucket("shuffle", n)
     pivots, digests = shuffle_tables(seed, rounds, n, nb)
     padded = np.zeros(nb, dtype=np.int32)
     padded[:n] = arr.astype(np.int32)
-    t_dispatch = _time.perf_counter()
-    # recompile-hazard: ok(n is the traced n_live value arg, shapes are bucketed)
-    out = _shuffle_kernel(
-        jnp.asarray(padded), jnp.asarray(pivots), jnp.asarray(digests),
-        jnp.int32(n),
-    )
-    dispatch_s = _time.perf_counter() - t_dispatch
-    compiled = device_telemetry.note_dispatch(op, (nb,), dispatch_s, mesh=0)
-    t_wait = _time.perf_counter()
-    shuffled = jax.device_get(out)
-    device_telemetry.record_batch(
-        op=op,
-        shape=(nb,),
-        n_live=n,
-        stages={"dispatch": dispatch_s,
-                "wait": _time.perf_counter() - t_wait},
-        trace_id=device_telemetry.active_trace_id(),
-        compiled=compiled,
-        mesh=0,
-    )
+    shuffled = SHUFFLE.call((padded, pivots, digests, np.int32(n)), n_live=n)
     return np.asarray(shuffled[:n], dtype=arr.dtype)
 
 
@@ -419,48 +379,19 @@ def proposer_select_device(
     ``compute_proposer_index`` result whenever ``found[s]``; a not-found
     slot (all :data:`PROPOSER_CANDIDATES` rejected) stays on the scalar
     walk."""
-    import time as _time
-
-    from .. import device_telemetry, fault_injection
-
-    op = "proposer_select"
     active = np.asarray(active_indices, dtype=np.int64)
     m = int(active.shape[0])
     s = len(slot_seeds)
     if m == 0 or s == 0:
         return (np.full(s, -1, dtype=np.int64), np.zeros(s, dtype=bool))
-    nb = _bucket(op, m)
-    if fault_injection.ACTIVE:
-        if not device_telemetry.COMPILE_CACHE.seen(op, (nb,), mesh=0):
-            fault_injection.check("device.compile", op=op)
-        fault_injection.check("device.dispatch", op=op)
+    nb = _bucket("proposer_select", m)
     seed_words, pivots, rbytes = proposer_tables(slot_seeds, rounds, m)
     eff = np.asarray(effective_balance, dtype=np.int64)
     eff_act = np.zeros(nb, dtype=np.int64)
     eff_act[:m] = eff[active]
-    with jax.enable_x64(True):
-        t_dispatch = _time.perf_counter()
-        # recompile-hazard: ok(m is the traced m_live value arg, shapes are bucketed)
-        out = _proposer_kernel(
-            jnp.asarray(seed_words), jnp.asarray(pivots),
-            jnp.asarray(rbytes), jnp.asarray(eff_act), jnp.int32(m),
-            jnp.int64(int(max_effective_balance)),
-        )
-        dispatch_s = _time.perf_counter() - t_dispatch
-        compiled = device_telemetry.note_dispatch(op, (nb,), dispatch_s,
-                                                 mesh=0)
-        t_wait = _time.perf_counter()
-        pos, found = jax.device_get(out)
-    device_telemetry.record_batch(
-        op=op,
-        shape=(nb,),
-        n_live=m,
-        stages={"dispatch": dispatch_s,
-                "wait": _time.perf_counter() - t_wait},
-        trace_id=device_telemetry.active_trace_id(),
-        compiled=compiled,
-        mesh=0,
-    )
+    pos, found = PROPOSER.call(
+        (seed_words, pivots, rbytes, eff_act, np.int32(m),
+         np.int64(int(max_effective_balance))), n_live=m)
     pos = np.asarray(pos, dtype=np.int64)
     found = np.asarray(found, dtype=bool)
     proposer = np.where(found, active[np.maximum(pos, 0)], -1)
@@ -595,87 +526,42 @@ def epoch_boundary_device(plan: BoundaryPlan, *, in_leak: bool):
     ejection_mask, queue_mask, activation_mask, shuffling, proposer,
     found)``; per-validator arrays sliced to ``plan.n``, the shuffling to
     ``plan.m``, proposer/found per slot."""
-    import time as _time
-
-    from .. import device_mesh, device_telemetry, fault_injection
-
-    op = "epoch_boundary_leak" if in_leak else "epoch_boundary"
     n, m = plan.n, plan.m
-    nb = _bucket("epoch_boundary", n)
-    mesh = device_mesh.size() if device_mesh.enabled() else 0
-    np_ = device_mesh.pad_rows(nb) if mesh else nb
-    if fault_injection.ACTIVE:
-        if not device_telemetry.COMPILE_CACHE.seen(op, (np_,), mesh=mesh):
-            fault_injection.check("device.compile", op=op)
-        fault_injection.check("device.dispatch", op=op)
+    np_ = BOUNDARY.rows(_bucket("epoch_boundary", n))
     sh_pivots, sh_digests = shuffle_tables(
         plan.attester_seed, plan.rounds, m, np_)
     seed_words, prop_pivots, rbytes = proposer_tables(
         plan.slot_seeds, plan.rounds, m)
     active_padded = np.zeros(np_, dtype=np.int32)
     active_padded[:m] = plan.active_idx.astype(np.int32)
-    with jax.enable_x64(True):
-        batched = (
-            np.asarray(plan.effective_balance, dtype=np.int64),
-            np.asarray(plan.activation_epoch, dtype=np.int64),
-            np.asarray(plan.exit_epoch, dtype=np.int64),
-            np.asarray(plan.withdrawable_epoch, dtype=np.int64),
-            np.asarray(plan.slashed, dtype=bool),
-            np.asarray(plan.prev_part, dtype=np.int64),
-            np.asarray(plan.inactivity, dtype=np.int64),
-            np.asarray(plan.balance, dtype=np.int64),
-            np.asarray(plan.activation_eligibility_epoch, dtype=np.int64),
-            np.asarray(plan.eb_cap, dtype=np.int64),
-        )
-        if np_ != n:
-            batched = tuple(
-                device_mesh.grow_rows(a, np_, f)
-                for a, f in zip(batched, _PAD_FILLS)
-            )
-        batched = batched + (active_padded,)
-        tables = (sh_pivots, sh_digests, seed_words, prop_pivots, rbytes)
-        scalars = (
-            plan.previous_epoch, plan.base_reward_per_increment,
-            plan.total_active_balance, plan.increment,
-            plan.inactivity_score_bias,
-            plan.inactivity_score_recovery_rate, plan.quotient,
-            plan.current_epoch, plan.downward, plan.upward,
-            plan.ejection_balance, plan.far_future, plan.finalized_epoch,
-            plan.max_effective_balance, plan.queue_lo, plan.queue_hi,
-        )
-        t_dispatch = _time.perf_counter()
-        if mesh:
-            entry = _sharded_entry()
-            placed = entry.place(
-                *batched, *(jnp.asarray(t) for t in tables),
-                *(jnp.int64(s) for s in scalars), jnp.int32(m),
-            )
-            out = entry(*placed, in_leak=bool(in_leak))
-        else:
-            out = _boundary_kernel(
-                *(jnp.asarray(a) for a in batched),
-                *(jnp.asarray(t) for t in tables),
-                *(jnp.int64(s) for s in scalars), jnp.int32(m),
-                in_leak=bool(in_leak),
-            )
-        dispatch_s = _time.perf_counter() - t_dispatch
-        compiled = device_telemetry.note_dispatch(op, (np_,), dispatch_s,
-                                                 mesh=mesh)
-        t_wait = _time.perf_counter()
-        (new_inactivity, balance_delta, new_eff, ejection_mask, queue_mask,
-         activation_mask, shuffled, proposer, found) = jax.device_get(out)
-    device_telemetry.record_batch(
-        op=op,
-        shape=(np_,),
-        n_live=n,
-        stages={"dispatch": dispatch_s,
-                "wait": _time.perf_counter() - t_wait},
-        trace_id=device_telemetry.active_trace_id(),
-        compiled=compiled,
-        mesh=mesh,
-        shard_live=(_sharded_entry().shard_live_counts(n, np_)
-                    if mesh else None),
+    batched = (
+        np.asarray(plan.effective_balance, dtype=np.int64),
+        np.asarray(plan.activation_epoch, dtype=np.int64),
+        np.asarray(plan.exit_epoch, dtype=np.int64),
+        np.asarray(plan.withdrawable_epoch, dtype=np.int64),
+        np.asarray(plan.slashed, dtype=bool),
+        np.asarray(plan.prev_part, dtype=np.int64),
+        np.asarray(plan.inactivity, dtype=np.int64),
+        np.asarray(plan.balance, dtype=np.int64),
+        np.asarray(plan.activation_eligibility_epoch, dtype=np.int64),
+        np.asarray(plan.eb_cap, dtype=np.int64),
+        active_padded,
     )
+    tables = (sh_pivots, sh_digests, seed_words, prop_pivots, rbytes)
+    scalars = tuple(np.int64(v) for v in (
+        plan.previous_epoch, plan.base_reward_per_increment,
+        plan.total_active_balance, plan.increment,
+        plan.inactivity_score_bias,
+        plan.inactivity_score_recovery_rate, plan.quotient,
+        plan.current_epoch, plan.downward, plan.upward,
+        plan.ejection_balance, plan.far_future, plan.finalized_epoch,
+        plan.max_effective_balance, plan.queue_lo, plan.queue_hi,
+    ))
+    (new_inactivity, balance_delta, new_eff, ejection_mask, queue_mask,
+     activation_mask, shuffled, proposer, found) = BOUNDARY.call(
+        batched + tables + scalars + (np.int32(m),), n_live=n, rows=np_,
+        op="epoch_boundary_leak" if in_leak else "epoch_boundary",
+        static={"in_leak": bool(in_leak)})
     return (
         np.asarray(new_inactivity[:n], dtype=np.int64),
         np.asarray(balance_delta[:n], dtype=np.int64),

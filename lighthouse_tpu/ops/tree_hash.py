@@ -13,9 +13,9 @@ changed.  This module is that blueprint on the device stack:
   field walk the tree ``SUBTREE_DEPTH`` levels per dispatch instead of one
   pair-hash round trip per level — log32 dispatches for a registry, not
   log2.  Batched over the subtree axis, bucketed (:data:`N_BUCKETS`),
-  mesh-shardable (``ops/batch_axes.py`` entry), supervised
-  (``device_supervisor.run("tree_hash", ...)`` — watchdog, split-retry,
-  breaker → the hashlib host model).
+  mesh-shardable (``ops/batch_axes.py`` entry), supervised through
+  ``ops/dispatch.py`` (watchdog, split-retry, breaker → the hashlib host
+  model).
 - :class:`DeviceLeafTree` — the cached-tree-hash layer: leaf chunks and all
   interior levels stay HOST-side as numpy arrays; ``update`` diffs the new
   leaves against the cache with one vectorized compare and re-hashes only
@@ -44,6 +44,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from . import dispatch
 from .sha256_device import _H0, _K, _PAD_WORDS
 
 #: Depth of the fused subtree program: 32 leaf chunks -> 1 root per subtree,
@@ -55,8 +56,6 @@ SUBTREE_LEAVES = 1 << SUBTREE_DEPTH
 #: 2^20-chunk level — the mainnet validator registry — in a single
 #: dispatch.  Bigger levels chunk through the top bucket.
 N_BUCKETS = (8, 128, 2048, 32768)
-
-ENTRY_KEY = "lighthouse_tpu/ops/tree_hash.py:_tree_hash_subtrees"
 
 #: Precomputed zero-subtree roots (index d = root of a depth-d all-zero
 #: tree) — the right-edge padding vocabulary, identical to types/ssz.py's
@@ -188,19 +187,9 @@ def _tree_hash_subtrees(leaves):
     return tuple(levels)
 
 
-#: device_mesh.ShardedEntry for the subtree kernel (lazy).
-_SHARDED_ENTRY = None
-
-
-def _sharded_entry():
-    global _SHARDED_ENTRY
-    if _SHARDED_ENTRY is None:
-        from .. import device_mesh
-
-        _SHARDED_ENTRY = device_mesh.ShardedEntry(
-            ENTRY_KEY, _tree_hash_subtrees.__wrapped__
-        )
-    return _SHARDED_ENTRY
+#: The subtree kernel behind the dispatch seam: every subtree is
+#: independent, so a mesh splits the subtree axis with no collectives.
+PROGRAM = dispatch.Program(_tree_hash_subtrees, fills=(0,))
 
 
 # -------------------------------------------------------------- host driver
@@ -255,43 +244,15 @@ def _host_subtree_levels(words: np.ndarray) -> List[np.ndarray]:
     return out
 
 
-def _dispatch_subtrees(words: np.ndarray, mb: int, stages: dict,
-                       state: dict) -> List[np.ndarray]:
-    """Dispatch + wait for one bucket-padded subtree batch; runs on the
-    supervisor's watchdog worker.  Mesh on: the subtree axis pads to a mesh
-    multiple and shards over ``("dp",)`` (every subtree is independent —
-    pure data parallelism)."""
-    import time as _time
-
-    from .. import device_mesh, device_telemetry, fault_injection
-
-    mesh = 0
-    if device_mesh.enabled():
-        mesh = device_mesh.size()
-        mbp = device_mesh.pad_rows(mb)
-        words, mb = device_mesh.grow_rows(words, mbp, 0), mbp
-        state["mesh"], state["mb"] = mesh, mb
-        (placed,) = _sharded_entry().place(words)
-    if fault_injection.ACTIVE:
-        if not device_telemetry.COMPILE_CACHE.seen("tree_hash", (mb,),
-                                                   mesh=mesh):
-            fault_injection.check("device.compile", op="tree_hash")
-        fault_injection.check("device.dispatch", op="tree_hash")
-    t_dispatch = _time.perf_counter()
-    if mesh:
-        dev_out = _sharded_entry()(placed)
-    else:
-        # mb is bucket-quantized by the caller
-        dev_out = _tree_hash_subtrees(jnp.asarray(words))
-    dispatch_s = _time.perf_counter() - t_dispatch
-    stages["dispatch"] = dispatch_s
-    if device_telemetry.note_dispatch("tree_hash", (mb,), dispatch_s,
-                                      mesh=mesh):
-        state["compiled"] = True
-    t_wait = _time.perf_counter()
-    out = [np.asarray(lv, dtype=np.uint32) for lv in dev_out]
-    stages["wait"] = _time.perf_counter() - t_wait
-    return out
+def _padded(words: np.ndarray) -> np.ndarray:
+    """Subtree words padded with zero subtrees to their bucket."""
+    m = words.shape[0]
+    mb = _bucket(m)
+    if mb == m:
+        return words
+    padded = np.zeros((mb,) + words.shape[1:], dtype=np.uint32)
+    padded[:m] = words
+    return padded
 
 
 def hash_subtree_levels(chunks: np.ndarray) -> List[np.ndarray]:
@@ -304,8 +265,6 @@ def hash_subtree_levels(chunks: np.ndarray) -> List[np.ndarray]:
     (each subtree's nodes are contiguous).  Supervised: a hung or failing
     dispatch resolves through the hashlib golden model, split-retried once
     first (subtrees are independent, halves concatenate exactly)."""
-    from .. import device_supervisor, device_telemetry
-
     n = int(chunks.shape[0])
     if n == 0 or n % SUBTREE_LEAVES:
         raise ValueError(f"level of {n} chunks is not a subtree multiple")
@@ -322,80 +281,15 @@ def hash_subtree_levels(chunks: np.ndarray) -> List[np.ndarray]:
         return [np.concatenate(level) for level in zip(*parts)]
 
     words = _chunks_to_words(chunks)
-    mb = _bucket(m)
-    if mb != m:
-        padded = np.zeros((mb,) + words.shape[1:], dtype=np.uint32)
-        padded[:m] = words
-        words = padded
-    holder: dict = {}
-
-    def device_fn() -> List[np.ndarray]:
-        stages_local: dict = {}
-        state_local: dict = {}
-        try:
-            out = _dispatch_subtrees(words, mb, stages_local, state_local)
-            return [lv[:m] for lv in out]
-        finally:
-            holder["stages"] = stages_local
-            holder["state"] = state_local
-
-    def _device_half(half_words: np.ndarray) -> List[np.ndarray]:
-        # Raw device path for one half — must NOT recurse into the
-        # supervised entry (the halves already run on the watchdog worker).
-        k = half_words.shape[0]
-        kb = _bucket(k)
-        if kb != k:
-            grown = np.zeros((kb,) + half_words.shape[1:], dtype=np.uint32)
-            grown[:k] = half_words
-            half_words = grown
-        out = _dispatch_subtrees(half_words, kb, {}, {})
-        return [lv[:k] for lv in out]
-
-    def split_fn():
-        mid = m // 2
-        if mid == 0:
-            raise ValueError("single-subtree batch cannot split")
-        return [
-            lambda: _device_half(words[:mid]),
-            lambda: _device_half(words[mid:m]),
-        ]
-
-    def combine_fn(halves):
-        return [np.concatenate(level) for level in zip(*halves)]
-
-    info: dict = {}
-    out_words = device_supervisor.run(
-        "tree_hash",
-        device_fn,
-        host_fn=lambda: _host_subtree_levels(words[:m]),
-        split_fn=split_fn,
-        combine_fn=combine_fn,
-        info=info,
-    )
-    reason = info.get("fallback_reason")
-    stages: dict = {}
-    compiled = False
-    state: dict = {}
-    if reason != "dispatch_timeout":
-        stages = holder.get("stages") or {}
-        state = holder.get("state") or {}
-        compiled = state.get("compiled", False)
-    mesh = state.get("mesh", 0)
-    mbp = state.get("mb", mb)
-    device_telemetry.record_batch(
-        op="tree_hash",
-        shape=(mbp,),
+    padded = _padded(words)
+    out_words = PROGRAM.run(
+        lambda: dispatch.Placed(PROGRAM, (padded,)),
         n_live=m,
-        stages=stages or None,
-        host_fallback=info.get("route") == "host",
-        fallback_reason=reason,
-        trace_id=device_telemetry.active_trace_id(),
-        compiled=compiled,
-        breaker_state=info.get("breaker_state"),
-        dispatched=reason != "breaker_open",
-        mesh=mesh,
-        shard_live=(_sharded_entry().shard_live_counts(m, mbp)
-                    if mesh else None),
+        host_fn=lambda: _host_subtree_levels(words),
+        finish=lambda out, k: [lv[:k] for lv in out],
+        half=lambda lo, hi: (_padded(words[lo:hi]),),
+        combine=lambda halves: [np.concatenate(lv) for lv in zip(*halves)],
+        bucket=(padded.shape[0],),
     )
     return [_words_to_chunks(lv) for lv in out_words]
 

@@ -40,13 +40,13 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .. import autotune
+from .. import autotune, device_supervisor, metrics, tracing
 from ..crypto.bls import curve
 from ..crypto.bls.backends.host import _rand_scalars
 from ..crypto.bls.fields import Fq2
 from ..crypto.bls.hash_to_curve import hash_to_g2_uncleared
 from ..crypto.bls.params import DST, G1_X, G1_Y, P
-from . import ec, fq, pairing, tower
+from . import dispatch, ec, fq, pairing, tower
 
 _NEG_G1 = ec.g1_to_limbs((curve.G1[0], -curve.G1[1]))
 _G2_GEN_AFF = (
@@ -185,6 +185,20 @@ def bucket_shape(batch) -> Tuple[int, ...]:
     return nb, kb, int(pk[0].shape[0]), int(rows.shape[1])
 
 
+ENTRY_KEY = "lighthouse_tpu/ops/verify.py:_device_verify"
+
+#: ``_device_verify`` behind the dispatch seam.  Its pad rows are exact
+#: neutral elements (identity points, generator hash slots, zero weights,
+#: dead ``live`` rows, no key rows); key rows pad to their own mesh multiple.
+PROGRAM = dispatch.Program(
+    _device_verify,
+    fills=(ec.g1_to_limbs(None), ec.g2_to_limbs(None), _G2_GEN_AFF, 0, False,
+           -1),
+    shape=bucket_shape,
+    spans="device_batch",
+)
+
+
 def lower_for_stages(shape: Tuple[int, ...]):
     """``_device_verify`` lowered at the bucket ``shape`` (see
     :func:`bucket_shape`) with the dispatched arguments' types, so jit's
@@ -214,84 +228,6 @@ def _hash_to_g2_uncleared_cached(message: bytes,
             _hash_cache.clear()
         _hash_cache[key] = pt
     return pt
-
-
-#: device_mesh.ShardedEntry for the verifier (lazy: the registry-derived
-#: specs and the per-topology jitted wrapper live in device_mesh).
-_SHARDED_ENTRY = None
-
-ENTRY_KEY = "lighthouse_tpu/ops/verify.py:_device_verify"
-
-
-def _sharded_entry():
-    global _SHARDED_ENTRY
-    if _SHARDED_ENTRY is None:
-        from .. import device_mesh
-
-        _SHARDED_ENTRY = device_mesh.ShardedEntry(
-            ENTRY_KEY, _device_verify.__wrapped__
-        )
-    return _SHARDED_ENTRY
-
-
-def _pad_host_rows(host_batch: tuple, nbp: int) -> tuple:
-    """Grow the batch axis to ``nbp`` rows with the exact neutral padding
-    ``build_batch`` uses (identity points, generator hash slots, zero
-    weights, dead ``live`` rows, no key rows) — the mesh-divisibility pad.
-    Key rows of a batch whose sets span rows grow to their own multiple of
-    the mesh size."""
-    from .. import device_mesh
-
-    pk, sig, msg, wbits, live, rows = host_batch
-    nb = live.shape[0]
-    nrp = nbp if rows is None else device_mesh.pad_rows(int(pk[0].shape[0]))
-    if nbp == nb and nrp == pk[0].shape[0]:
-        return host_batch
-    id1, id2 = ec.g1_to_limbs(None), ec.g2_to_limbs(None)
-    grow = device_mesh.grow_rows
-    pk = tuple(grow(pk[c], nrp, id1[c]) for c in range(3))
-    sig = tuple(grow(sig[c], nbp, id2[c]) for c in range(3))
-    msg = tuple(grow(msg[c], nbp, _G2_GEN_AFF[c]) for c in range(2))
-    wbits = grow(wbits, nbp, 0)
-    live = grow(live, nbp, False)
-    if rows is not None:
-        rows = grow(rows, nbp, -1)
-    return pk, sig, msg, wbits, live, rows
-
-
-def place_batch(host_batch: tuple) -> Tuple[tuple, int, int]:
-    """Stage 1b — upload a marshalled host batch to the device(s).
-
-    Mesh on: pad the batch axis to a multiple of the mesh size and upload
-    through the mesh placer (``device_mesh.ShardedEntry.place`` — batched
-    args shard axis 0 over ``("dp",)``).  Mesh off: plain single-device
-    arrays, byte-for-byte the pre-mesh path.  Returns ``(placed_args,
-    mesh_size, topology_generation)`` so a dispatch can detect a reshard
-    that happened between placement and execution."""
-    from .. import device_mesh
-
-    # Generation is snapshotted BEFORE padding/placement (but after the
-    # lazy configure `enabled()` may trigger): a reshard landing mid-place
-    # leaves this batch tagged with the pre-reshard generation, so
-    # ensure_placed() re-places it instead of dispatching arrays sharded
-    # for a dead topology.
-    meshed = device_mesh.enabled()
-    gen = device_mesh.generation()
-    if meshed:
-        entry = _sharded_entry()
-        nbp = device_mesh.pad_rows(int(host_batch[4].shape[0]))
-        placed = entry.place(*_pad_host_rows(host_batch, nbp))
-        return placed, device_mesh.size(), gen
-    pk, sig, msg, wbits, live, rows = host_batch
-    placed = (
-        tuple(jnp.asarray(a) for a in pk),
-        tuple(jnp.asarray(a) for a in sig),
-        tuple(jnp.asarray(a) for a in msg),
-        jnp.asarray(wbits),
-        jnp.asarray(live),
-        None if rows is None else jnp.asarray(rows),
-    )
-    return placed, 0, gen
 
 
 #: Host key arrays kept for the next batch of the same (rows, width): a
@@ -335,7 +271,7 @@ def build_batch(sets, rands) -> Optional[tuple]:
     """Validate + marshal signature sets into padded HOST arrays (numpy,
     bucket-shaped): ``(pk, sig, msg, wbits, live, rows)``, the arguments of
     ``_device_verify``.  Placement — single-device or mesh-sharded — is
-    :func:`place_batch`; jit accepts the numpy arrays directly, so callers
+    ``dispatch.Placed``; jit accepts the numpy arrays directly, so callers
     that dispatch these straight into ``_device_verify`` still work.
 
     Set i's keys fill key row i, and ``rows`` is None, while every set fits
@@ -352,8 +288,6 @@ def build_batch(sets, rands) -> Optional[tuple]:
     (``marshal_subgroup``), every message's hash to E2 (``marshal_hash_to_g2``),
     then the limbs of all sets (``marshal_pack``).
     """
-    from .. import tracing
-
     n = len(sets)
     # The set axis buckets against the LIVE vocabulary (static N_BUCKETS
     # plus any controller-adopted overlay buckets); the key axis stays
@@ -426,104 +360,18 @@ def build_batch(sets, rands) -> Optional[tuple]:
     return tuple(pk), tuple(sig), tuple(msg), wbits, live, rows
 
 
-def _device_batch_verdict(built: "BuiltBatch", stages: dict,
-                          state: dict) -> bool:
-    """Dispatch + block-until-ready + verdict for one marshalled batch.
-
-    Runs on the supervisor's watchdog worker thread (the caller's trace
-    context is re-attached there), so a hung ``block_until_ready`` strands
-    the worker, never block import.  Raises
-    ``device_supervisor.HostFallback("w_at_infinity")`` when the device
-    disclaims its own Miller value — the supervisor then re-verifies on the
-    host through the one shared fallback path.
-    """
-    from .. import device_supervisor, device_telemetry, fault_injection, metrics, tracing
-
-    built.ensure_placed()  # a reshard since placement re-pads + re-uploads
-    batch, bucket, mesh = built.batch, built.bucket, built.mesh
-    nb, kb = built.nb, built.kb
-    if fault_injection.ACTIVE:
-        if not device_telemetry.COMPILE_CACHE.seen("bls_verify", bucket,
-                                                   mesh=mesh):
-            fault_injection.check("device.compile", op="bls_verify")
-        fault_injection.check("device.dispatch", op="bls_verify")
-    with tracing.span(
-        "device_batch_dispatch", hist=metrics.DEVICE_DISPATCH_SECONDS,
-        n_bucket=nb, k_bucket=kb, r_bucket=built.key_rows, mesh=mesh,
-    ) as sp_dispatch:
-        if mesh:
-            fe, w_z = _sharded_entry()(*batch)
-        else:
-            fe, w_z = _device_verify(*batch)
-    # First dispatch of a shape pays trace+compile inside the call itself:
-    # the dispatch duration IS the compile-time observation for that shape.
-    compiled = device_telemetry.note_dispatch(
-        "bls_verify", bucket, sp_dispatch.duration, mesh=mesh
-    )
-    if compiled:
-        sp_dispatch.fields["compiled"] = True
-        state["compiled"] = True
-    stages["dispatch"] = sp_dispatch.duration
-    with tracing.span(
-        "device_batch_wait", hist=metrics.DEVICE_BLOCK_UNTIL_READY_SECONDS,
-        n_bucket=nb, k_bucket=kb,
-    ) as sp_wait:
-        jax.block_until_ready((fe, w_z))
-    stages["wait"] = sp_wait.duration
-    with tracing.span(
-        "device_batch_verdict", hist=metrics.DEVICE_VERDICT_SECONDS
-    ) as sp_verdict:
-        try:
-            if tower.fq2_from_limbs(np.asarray(w_z)).is_zero():
-                # W at infinity: Miller value was poisoned; decide on the
-                # host — via the supervisor, so every fallback reason shares
-                # one mechanism and one counter.
-                sp_verdict.fields["host_fallback"] = True
-                raise device_supervisor.HostFallback("w_at_infinity")
-            ok = pairing.fe_is_one(fe)
-            if (
-                fault_injection.ACTIVE
-                and fault_injection.fire("device.result", op="bls_verify")
-                == "corrupt"
-            ):
-                tracing.annotate(corrupted_verdict=True)
-                ok = False
-        finally:
-            stages["verdict"] = sp_verdict.duration
-    return ok
-
-
-def _device_verify_subset(subset, seed: Optional[bytes]) -> bool:
-    """One half of a split-batch retry: the raw device path at the half's
-    own bucket shape.  No stage spans (the parent batch's flight record
-    carries the split outcome); the dispatch still registers in the compile
-    mirror — a half bucket can be a first-seen shape."""
-    from .. import device_supervisor, device_telemetry, fault_injection
-
-    rands = _rand_scalars(len(subset), seed)
-    host_batch = build_batch(subset, rands)
-    if host_batch is None:
-        return False
-    batch, mesh, _ = place_batch(host_batch)
-    if fault_injection.ACTIVE:
-        fault_injection.check("device.dispatch", op="bls_verify")
-    import time as _time
-
-    t0 = _time.perf_counter()
-    if mesh:
-        fe, w_z = _sharded_entry()(*batch)
-    else:
-        fe, w_z = _device_verify(*batch)
-    device_telemetry.note_dispatch(
-        "bls_verify", bucket_shape(batch), _time.perf_counter() - t0, mesh=mesh
-    )
-    jax.block_until_ready((fe, w_z))
+def _verdict(out) -> bool:
+    """The verdict of one dispatched batch.  W at infinity poisons the
+    Miller value: the device disclaims it, and the supervisor decides on
+    the host through the one shared fallback path."""
+    fe, w_z = out
     if tower.fq2_from_limbs(np.asarray(w_z)).is_zero():
+        tracing.annotate(host_fallback=True)
         raise device_supervisor.HostFallback("w_at_infinity")
     return pairing.fe_is_one(fe)
 
 
-class BuiltBatch:
+class BuiltBatch(dispatch.Placed):
     """A marshalled batch between the build and dispatch stages.
 
     The two stages are separately callable so the async device pipeline
@@ -531,58 +379,25 @@ class BuiltBatch:
     (its builder thread calls :func:`build_device_batch`) with the in-flight
     device execution of batch N (its executor thread calls
     :func:`execute_built_batch`).  ``verify_signature_sets_device`` is the
-    two stages run back-to-back — the direct, non-pipelined path.
+    two stages run back-to-back — the direct, non-pipelined path."""
 
-    The HOST arrays are retained next to the placed ones: a mesh reshard
-    between build and dispatch (a per-device breaker trip) invalidates the
-    placement — shards on a removed device, a batch-axis pad for the wrong
-    mesh size — and :meth:`ensure_placed` re-pads + re-uploads from the
-    host copy under the surviving topology."""
+    __slots__ = ("sets", "seed", "live_keys")
 
-    __slots__ = ("sets", "seed", "host", "batch", "bucket", "mesh",
-                 "generation", "live_keys", "setup_s")
-
-    def __init__(self, sets, seed, host_batch, setup_s: float):
-        from .. import tracing
-
+    def __init__(self, sets, seed, host_batch):
         self.sets = sets
         self.seed = seed
-        self.host = host_batch
-        with tracing.span("marshal_upload"):
-            self.batch, self.mesh, self.generation = place_batch(host_batch)
-        self.bucket = bucket_shape(self.batch)
         self.live_keys = sum(len(s.signing_keys) for s in sets)
-        self.setup_s = setup_s
-
-    def ensure_placed(self) -> None:
-        """Re-place after any topology change since the last placement
-        (mesh enabled/disabled/resharded — all bump the generation)."""
-        from .. import device_mesh
-
-        if device_mesh.generation() != self.generation:
-            self.batch, self.mesh, self.generation = place_batch(self.host)
-            self.bucket = bucket_shape(self.batch)
+        with tracing.span("marshal_upload"):
+            super().__init__(PROGRAM, host_batch)
 
     def release(self) -> None:
         """Return the host key arrays for the next batch of their shape,
         once the device is done with this one; the batch cannot be placed
         again."""
-        host, self.host, self.batch = self.host, None, None
+        host = self.host
+        super().release()
         if host is not None:
             _return_key_arrays(host[0])
-
-    @property
-    def nb(self) -> int:
-        return self.bucket[0]
-
-    @property
-    def kb(self) -> int:
-        return self.bucket[1]
-
-    @property
-    def key_rows(self) -> int:
-        """Rows of the key axis: ``nb``, or ``nr`` where sets span rows."""
-        return self.bucket[2] if len(self.bucket) > 2 else self.bucket[0]
 
 
 def build_device_batch(sets, seed: Optional[bytes] = None) -> Optional[BuiltBatch]:
@@ -592,8 +407,6 @@ def build_device_batch(sets, seed: Optional[bytes] = None) -> Optional[BuiltBatc
     validation already decides False (bad/missing signature, empty key
     list).  Safe to call from any thread; no device work happens here
     beyond the host→device array uploads."""
-    from .. import metrics, tracing
-
     sets = list(sets)
     if not sets or len(sets) > MAX_SETS_PER_DISPATCH:
         raise ValueError(
@@ -604,109 +417,38 @@ def build_device_batch(sets, seed: Optional[bytes] = None) -> Optional[BuiltBatc
         "device_batch_setup", hist=metrics.DEVICE_BATCH_SETUP_SECONDS,
         n_sets=len(sets),
     ) as sp_setup:
-        rands = _rand_scalars(len(sets), seed)
-        host_batch = build_batch(sets, rands)
+        host_batch = build_batch(sets, _rand_scalars(len(sets), seed))
         if host_batch is None:
             return None
-        # Placement (the mesh-pad + sharded upload, or the plain
-        # single-device upload) is part of the build stage so the pipeline
-        # overlaps it with the in-flight batch like the rest of setup.
-        built = BuiltBatch(sets, seed, host_batch, 0.0)
+        # The upload is part of the build stage, so the pipeline overlaps
+        # it with the in-flight batch like the rest of setup.
+        built = BuiltBatch(sets, seed, host_batch)
     built.setup_s = sp_setup.duration
     return built
 
 
 def execute_built_batch(built: BuiltBatch, *, n_groups: int = 1,
                         work_mix: Optional[dict] = None) -> bool:
-    """Stage 2 — supervised dispatch + wait + verdict for a built batch.
-
-    Runs under the device supervisor (watchdog, one split-batch retry, the
-    per-op circuit breaker routing to the host golden model) and records the
-    batch in the flight recorder.  ``n_groups``/``work_mix`` attribute a
+    """Stage 2 — supervised dispatch + wait + verdict for a built batch
+    (``ops/dispatch.py``: watchdog, one split-batch retry at the halves'
+    own buckets, the circuit breaker routing to the host golden model, the
+    flight record).  ``n_groups``/``work_mix`` attribute a
     pipeline-coalesced batch's composition in the flight record."""
-    from .. import device_supervisor, device_telemetry, tracing
     from ..crypto.bls.backends import host
 
     sets, seed = built.sets, built.seed
-    stages = {"setup": built.setup_s}
-    # The watchdog worker writes stage durations into dicts IT owns and
-    # publishes them via this one-slot holder when the device fn finishes.
-    # The caller merges only when the worker completed (never on a
-    # dispatch timeout, where the abandoned worker may still be writing) —
-    # sharing the dicts directly would race record_batch's iteration.
-    holder: dict = {}
-
-    def device_fn():
-        stages_local: dict = {}
-        state_local = {"compiled": False}
-        try:
-            ok = _device_batch_verdict(built, stages_local, state_local)
-            holder["done"] = True  # the device has read every input
-            return ok
-        finally:
-            holder["stages"] = stages_local
-            holder["state"] = state_local
-
-    def split_fn():
-        mid = len(sets) // 2
-        if mid == 0:
-            raise ValueError("single-set batch cannot split")
-        return [
-            lambda: _device_verify_subset(sets[:mid], seed),
-            lambda: _device_verify_subset(sets[mid:], seed),
-        ]
-
-    info: dict = {}
-    ok = device_supervisor.run(
-        "bls_verify",
-        device_fn,
-        host_fn=lambda: host.verify_signature_sets(sets, seed=seed),
-        split_fn=split_fn,
-        combine_fn=all,
-        info=info,
-    )
-    host_fallback = info.get("route") == "host"
-    reason = info.get("fallback_reason")
-    compiled = False
-    if reason != "dispatch_timeout":
-        stages.update(holder.get("stages") or {})
-        compiled = (holder.get("state") or {}).get("compiled", False)
-    if holder.get("done"):
-        built.release()
-    # built.nb/mesh read AFTER the run: a mid-run reshard re-placed the
-    # batch, and the record must describe the topology that executed.
-    mesh = built.mesh
-    shard_live = (
-        _sharded_entry().shard_live_counts(len(sets), built.nb)
-        if mesh else None
-    )
-    rec = device_telemetry.record_batch(
-        op="bls_verify",
-        shape=built.bucket,
+    return PROGRAM.run(
+        built,
         n_live=len(sets),
+        host_fn=lambda: host.verify_signature_sets(sets, seed=seed),
+        verdict=_verdict,
+        half=lambda lo, hi: build_batch(sets[lo:hi],
+                                        _rand_scalars(hi - lo, seed)),
+        combine=all,
         live_keys=built.live_keys,
-        key_rows=built.key_rows,
         n_groups=n_groups,
         work_mix=work_mix,
-        stages=stages,
-        verdict=ok,
-        host_fallback=host_fallback,
-        fallback_reason=reason,
-        trace_id=device_telemetry.active_trace_id(),
-        compiled=compiled,
-        breaker_state=info.get("breaker_state"),
-        # breaker-OPEN batches never reached the device: keep them out of
-        # the occupancy/wasted-lane tuning data.
-        dispatched=reason != "breaker_open",
-        mesh=mesh,
-        shard_live=shard_live,
     )
-    # Reverse link: the enclosing span (device_verify when routed through
-    # the backend) carries the flight-recorder seq of this batch.
-    tracing.annotate(flight_seq=rec["seq"])
-    if host_fallback:
-        tracing.annotate(host_fallback=True)
-    return ok
 
 
 def verify_signature_sets_device(sets, seed: Optional[bytes] = None) -> bool:

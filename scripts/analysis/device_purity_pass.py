@@ -18,7 +18,7 @@ Flags, inside jit/Pallas functions:
 - ``global-mutation``  — ``global`` statements, or writes through a
   module-level name (cache dicts etc.)
 - ``unguarded-x64``    — 64-bit dtype references when the module never
-  touches ``enable_x64``
+  touches ``enable_x64`` nor declares an ``x64=True`` dispatch program
 
 Suppress intentional sites with ``# device-purity: ok(<reason>)``.
 """
@@ -72,6 +72,12 @@ def _module_guards_x64(tree: ast.Module) -> bool:
         if isinstance(node, (ast.Name, ast.Attribute)) and (
             terminal_name(node) == "enable_x64"
         ):
+            return True
+        # ``ops/dispatch.Program(..., x64=True)``: the seam dispatches the
+        # program under ``jax.enable_x64(True)``.
+        if (isinstance(node, ast.keyword) and node.arg == "x64"
+                and isinstance(node.value, ast.Constant)
+                and node.value.value is True):
             return True
     return False
 

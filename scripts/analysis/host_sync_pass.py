@@ -94,28 +94,10 @@ SYNC_WRAPPERS = frozenset({
 #: only THROUGH ops/verify.execute_built_batch (supervised), and the
 #: builder must never sync at all.
 SANCTIONED_CONTEXTS: Dict[str, Tuple[str, ...]] = {
-    # dispatch+wait+verdict for a bls batch — runs on the watchdog worker
-    "lighthouse_tpu/ops/verify.py": (
-        "_device_batch_verdict",
-        "_device_verify_subset",   # split-retry halves, same worker
-    ),
-    # sha pair-hash dispatch leg (device_fn/_device_half call into it)
-    "lighthouse_tpu/ops/sha256_device.py": ("_dispatch_batch",),
-    # tree-hash subtree dispatch leg — same watchdog-worker discipline
-    "lighthouse_tpu/ops/tree_hash.py": ("_dispatch_subtrees",),
-    # the epoch kernel entry IS the supervisor's device_fn (per_epoch.py)
-    "lighthouse_tpu/ops/epoch_device.py": ("epoch_deltas_device",),
-    # the fused boundary family (ISSUE 16): each dispatch entry is the
-    # supervised device_fn — dispatch+wait+device_get is its contract
-    "lighthouse_tpu/ops/shuffle_device.py": (
-        "shuffle_device",
-        "proposer_select_device",
-        "epoch_boundary_device",
-    ),
-    # kzg device_fn — supervised since this PR
-    "lighthouse_tpu/ops/kzg_device.py": (
-        "verify_kzg_proof_batch_device.device_fn",
-    ),
+    # the device leg of every bucketed op (dispatch, wait, verdict): it
+    # runs on the supervisor's watchdog worker, or, for the epoch family,
+    # inside the device_fn its caller supervises (per_epoch.py)
+    "lighthouse_tpu/ops/dispatch.py": ("execute",),
     # the autotune fq A/B probe: runs on the supervisor's autotune_probe
     # watchdog worker (autotune.measure_fq_backend wraps it) — timing the
     # dispatch IS its job

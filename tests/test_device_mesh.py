@@ -259,7 +259,7 @@ def test_mesh_pad_of_key_rows_and_their_index(monkeypatch):
     host_batch = verify.build_batch(sets, _rand_scalars(1, b"pad"))
     assert verify.bucket_shape(host_batch) == (1, 4, 4, 4)
     device_mesh.configure("auto")
-    padded = verify._pad_host_rows(host_batch, device_mesh.pad_rows(1))
+    padded = verify.PROGRAM.pad(host_batch, device_mesh.pad_rows(1))
     assert verify.bucket_shape(padded) == (8, 4, 8, 4)
     pk, sig, msg, wbits, live, rows = padded
     assert rows[0].tolist() == [0, 1, 2, -1] and (rows[1:] == -1).all()

@@ -215,7 +215,7 @@ class TestVerifyIntegration:
         # Force the W-at-infinity path: zero Z limbs out of the "device".
         fake_w_z = np.zeros((2, 25), np.int32)
         monkeypatch.setattr(
-            verify_mod, "_device_verify",
+            verify_mod.PROGRAM, "fn",
             lambda *batch: (np.zeros((12, 25), np.int32), fake_w_z),
         )
         before = metrics.DEVICE_HOST_FALLBACK.get(reason="w_at_infinity")

@@ -14,6 +14,7 @@ byte-identically.
 
 import ast
 import os
+import re
 import subprocess
 import sys
 import time
@@ -292,11 +293,14 @@ class TestHostSyncClassification:
         violations, sanctioned = host_sync_pass.classify(REPO_ROOT)
         assert violations == [], "\n".join(v.render() for v in violations)
         # the classifier itself must not be blind: the supervised device
-        # legs DO sync, and the pass must see them
-        assert len(sanctioned) >= 10
+        # leg of every op (ops/dispatch.py) DOES sync, and the pass must
+        # see both of its waits
         by_file = {v.path for v in sanctioned}
-        assert "lighthouse_tpu/ops/verify.py" in by_file
-        assert "lighthouse_tpu/ops/kzg_device.py" in by_file
+        assert by_file == {"lighthouse_tpu/ops/dispatch.py",
+                           "lighthouse_tpu/ops/fq.py", "bench.py"}
+        seam = [v for v in sanctioned
+                if v.path == "lighthouse_tpu/ops/dispatch.py"]
+        assert len(seam) == 2 and {v.context for v in seam} == {"execute"}
 
     def test_pipeline_builder_thread_is_sync_free(self):
         from analysis import host_sync_pass
@@ -322,6 +326,29 @@ class TestShardingRegistry:
         for key, spec in registry.items():
             assert spec["batch_axis"] == 0, key
             assert isinstance(spec["reduces_over_batch"], bool), key
+
+
+class TestDispatchSeam:
+    """ops/dispatch.py is the one module that knows the mesh, the fault
+    points, the compile mirror, the supervisor and the flight record; the
+    op modules reach them only through it."""
+
+    SEAM_ONLY = re.compile(
+        r"\bdevice_mesh\b|\bfault_injection\.(?:check|fire)\b"
+        r"|\bnote_dispatch\b|\brecord_batch\b"
+        r"|\bdevice_supervisor\.run\b|\b_sharded_entry\b")
+
+    def test_only_the_seam_dispatches(self):
+        ops_dir = os.path.join(REPO_ROOT, "lighthouse_tpu", "ops")
+        hits = []
+        for name in sorted(os.listdir(ops_dir)):
+            if not name.endswith(".py") or name == "dispatch.py":
+                continue
+            with open(os.path.join(ops_dir, name), encoding="utf-8") as f:
+                for lineno, line in enumerate(f, 1):
+                    if self.SEAM_ONLY.search(line):
+                        hits.append(f"ops/{name}:{lineno}: {line.strip()}")
+        assert hits == [], "dispatch outside ops/dispatch.py:\n" + "\n".join(hits)
 
 
 class TestCheckMetrics:
