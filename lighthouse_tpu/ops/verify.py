@@ -44,7 +44,7 @@ from .. import autotune, device_supervisor, metrics, tracing
 from ..crypto.bls import curve
 from ..crypto.bls.backends.host import _rand_scalars
 from ..crypto.bls.fields import Fq2
-from ..crypto.bls.hash_to_curve import hash_to_g2_uncleared
+from ..crypto.bls.hash_to_curve import hash_to_g2_uncleared_many
 from ..crypto.bls.params import DST, G1_X, G1_Y, P
 from . import dispatch, ec, fq, pairing, tower
 
@@ -214,20 +214,21 @@ def lower_for_stages(shape: Tuple[int, ...]):
 _hash_cache: dict = {}
 
 
-def _hash_to_g2_uncleared_cached(message: bytes,
-                                 misses: Optional[list] = None):
-    """``hash_to_g2_uncleared`` behind a bounded cache; a miss appends the
-    message to ``misses`` when given (the marshal span's ``n_hash_misses``)."""
-    key = bytes(message)
-    pt = _hash_cache.get(key)
-    if pt is None:
-        if misses is not None:
-            misses.append(key)
-        pt = hash_to_g2_uncleared(key, DST)
-        if len(_hash_cache) > 4096:
-            _hash_cache.clear()
-        _hash_cache[key] = pt
-    return pt
+def _hash_to_g2_uncleared_cached(messages) -> Tuple[list, int, int]:
+    """Each message's ``hash_to_g2_uncleared`` behind a bounded cache: the
+    distinct misses, in order, hashed in one ``hash_to_g2_uncleared_many``
+    call.  Returns the points, the number of misses (the marshal span's
+    ``n_hash_misses``) and the modular exponentiations they cost
+    (``modexp``)."""
+    keys = [bytes(m) for m in messages]
+    got = {key: _hash_cache.get(key) for key in keys}
+    misses = [key for key, pt in got.items() if pt is None]
+    pts, modexp = hash_to_g2_uncleared_many(misses, DST)
+    got.update(zip(misses, pts))
+    if len(_hash_cache) + len(misses) > 4096:
+        _hash_cache.clear()
+    _hash_cache.update(zip(misses, pts))
+    return [got[key] for key in keys], len(misses), modexp
 
 
 #: Host key arrays kept for the next batch of the same (rows, width): a
@@ -310,9 +311,10 @@ def build_batch(sets, rands) -> Optional[tuple]:
 
     with tracing.span("marshal_hash_to_g2", n_messages=n,
                       cleared_on_device=n) as sp_hash:
-        misses: list = []
-        hashes = [_hash_to_g2_uncleared_cached(s.message, misses) for s in sets]
-        sp_hash.fields["n_hash_misses"] = len(misses)
+        hashes, n_misses, modexp = _hash_to_g2_uncleared_cached(
+            s.message for s in sets)
+        sp_hash.fields["n_hash_misses"] = n_misses
+        sp_hash.fields["modexp"] = modexp
 
     n_keys = int(counts.sum())
     with tracing.span("marshal_pack", n_keys=n_keys, n_rows=n_rows, k_row=kb):

@@ -50,6 +50,12 @@ def _padding_row_sets():
                          (3, b"c" * 32), (1, b"b" * 32)])
 
 
+def _repeated_message_sets():
+    """Six sets over three messages, one of them signed four times."""
+    return _signed_sets([(1, b"a" * 32), (2, b"b" * 32), (1, b"a" * 32),
+                         (3, b"a" * 32), (1, b"c" * 32), (2, b"a" * 32)])
+
+
 def _edge_coordinate_sets():
     """Keys whose coordinates are 0, 1 and p - 1 (not curve points: the
     marshal packs what the key holds), under valid signatures."""
@@ -120,12 +126,23 @@ def _leaves(batch):
     (_full_bucket_sets, (2, 8)),
     (_padding_row_sets, (8, 4)),
     (_edge_coordinate_sets, (4, 4)),
-], ids=["mixed", "full_key_bucket", "padding_rows", "edge_coordinates"])
-def test_phased_marshal_matches_set_by_set_packing(make_sets, shape):
+    (_repeated_message_sets, (8, 4)),
+], ids=["mixed", "full_key_bucket", "padding_rows", "edge_coordinates",
+        "repeated_messages"])
+def test_phased_marshal_matches_set_by_set_packing(monkeypatch, make_sets,
+                                                   shape):
+    monkeypatch.setattr(verify_mod, "_hash_cache", {})
+    hashed = []
+    real = verify_mod.hash_to_g2_uncleared_many
+    monkeypatch.setattr(verify_mod, "hash_to_g2_uncleared_many",
+                        lambda ms, dst: hashed.append(list(ms)) or real(ms, dst))
     sets = make_sets()
     rands = _rand_scalars(len(sets), b"marshal-seed")
     with tracing.span("unit_marshal") as root:
         got = verify_mod.build_batch(sets, rands)
+    # each distinct message hashed once, in order of first use, in one call
+    distinct = list(dict.fromkeys(bytes(s.message) for s in sets))
+    assert hashed == [distinct]
     want = _reference_pack(sets, rands)
     assert got[4].shape == (shape[0],) and got[0][0].shape[:2] == shape
     assert got[5] is None and want[5] is None  # one key row a set
@@ -135,9 +152,11 @@ def test_phased_marshal_matches_set_by_set_packing(make_sets, shape):
         assert g.tobytes() == w.tobytes()
     names = [c.name for c in root.children]
     assert names == ["marshal_subgroup", "marshal_hash_to_g2", "marshal_pack"]
-    hashed = root.children[1]
-    assert hashed.fields["n_messages"] == len(sets)
-    assert hashed.fields["cleared_on_device"] == len(sets)
+    span = root.children[1]
+    assert span.fields["n_messages"] == len(sets)
+    assert span.fields["cleared_on_device"] == len(sets)
+    assert span.fields["n_hash_misses"] == len(distinct)
+    assert span.fields["modexp"] == 4 * len(distinct) + 3
     assert root.children[2].fields["n_keys"] == sum(
         len(s.signing_keys) for s in sets)
     assert root.children[2].fields["n_rows"] == len(sets)
@@ -180,10 +199,12 @@ def test_hash_misses_are_counted_where_the_cache_is_consulted(monkeypatch):
         verify_mod.build_batch(sets, _rand_scalars(len(sets), b"s"))
     # three distinct messages among four sets; the device clears all four
     assert root.children[1].fields["n_hash_misses"] == 3
+    assert root.children[1].fields["modexp"] == 4 * 3 + 3
     assert root.children[1].fields["cleared_on_device"] == 4
     with tracing.span("unit_marshal_again") as again:
         verify_mod.build_batch(sets, _rand_scalars(len(sets), b"s"))
     assert again.children[1].fields["n_hash_misses"] == 0
+    assert again.children[1].fields["modexp"] == 0  # every message a hit
     assert again.children[1].fields["cleared_on_device"] == 4
 
 
@@ -191,9 +212,9 @@ def test_hash_misses_are_counted_where_the_cache_is_consulted(monkeypatch):
 def test_bad_last_set_returns_none_before_any_hashing(monkeypatch, bad):
     monkeypatch.setattr(verify_mod, "_hash_cache", {})
     hashed = []
-    real = verify_mod.hash_to_g2_uncleared
-    monkeypatch.setattr(verify_mod, "hash_to_g2_uncleared",
-                        lambda m, dst: hashed.append(m) or real(m, dst))
+    real = verify_mod.hash_to_g2_uncleared_many
+    monkeypatch.setattr(verify_mod, "hash_to_g2_uncleared_many",
+                        lambda ms, dst: hashed.append(ms) or real(ms, dst))
     sets = _mixed_sets()
     last = sets[-1]
     if bad == "missing":
